@@ -20,6 +20,9 @@ def main() -> None:
                          "device_sweep,roofline")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (chaos_soak, composed_reservoirs, device_sweep, dfr_serving,
                    fig5_nrmse, fig6_ser, fig7_training_time, kernel_batching,
                    kernel_bench, roofline, streaming_fusion, table1_power,

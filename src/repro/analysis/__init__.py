@@ -16,7 +16,7 @@ contract catalog, `registry` the entry points, `cli` the gate.
 from .rules import (CALLBACK_PRIMS, VMEM_BYTES, DonationHonored,
                     MaxPallasCalls, MaxScans, NoDtypeAbove, NoHostCallback,
                     NoSilentUpcast, NoStateTensor, Program, Rule, Violation,
-                    VmemBudget, check_rules)
+                    VmemBudget, check_rules, mosaic_kernels)
 from .walker import (Intermediate, count_pallas_calls, count_scans,
                      eqn_paths, intermediate_records, intermediate_shapes,
                      max_intermediate_bytes, pallas_eqns,
@@ -29,7 +29,7 @@ __all__ = [
     "NoSilentUpcast", "NoStateTensor", "Program", "Rule", "Violation",
     "VmemBudget", "check_rules", "count_pallas_calls", "count_scans",
     "eqn_paths", "intermediate_records", "intermediate_shapes",
-    "max_intermediate_bytes", "pallas_eqns", "state_tensor_bytes",
+    "max_intermediate_bytes", "mosaic_kernels", "pallas_eqns", "state_tensor_bytes",
     "state_tensor_records", "trace_jaxpr", "walk_eqns",
     "walk_eqns_with_path",
 ]

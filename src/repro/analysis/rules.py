@@ -22,16 +22,22 @@ The built-in catalog covers the repo's load-bearing claims:
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels import VMEM_LIMIT_BYTES
 
 from .walker import (Intermediate, count_pallas_calls, count_scans, eqn_paths,
                      intermediate_records, pallas_eqns, state_tensor_records,
                      trace_jaxpr, walk_eqns_with_path)
 
-VMEM_BYTES = 16 * 2 ** 20      # v4/v5 VMEM per core; override per rule
+# The scoped-VMEM limit both kernels compile with (repro.kernels); override
+# per rule.
+VMEM_BYTES = VMEM_LIMIT_BYTES
 
 # Primitives that round-trip through the host from inside a jitted program.
 CALLBACK_PRIMS = frozenset({
@@ -376,6 +382,13 @@ class VmemBudget(Rule):
                 + sum(ref_bytes(v) for v in scratch))
 
     @staticmethod
+    def _block_dim(d) -> int:
+        """One BlockSpec dim as an int: ``Blocked``/``Element`` dims carry
+        ``block_size``; a squeezed (``None``/``Squeezed``) dim is 1."""
+        size = getattr(d, "block_size", d)
+        return size if isinstance(size, int) else 1
+
+    @staticmethod
     def _aligned(block_shape, full_shape, dtype):
         """None if OK, else a human-readable misalignment description."""
         from repro.kernels.dfr_scan import min_sublanes
@@ -396,8 +409,7 @@ class VmemBudget(Rule):
     def check(self, program: Program) -> list:
         out = []
         for eqn, path in pallas_eqns(program.closed_jaxpr):
-            kname = eqn.params.get("name_and_src_info", "")
-            kname = getattr(kname, "name", str(kname))
+            kname = eqn.params.get("name") or "<unnamed>"
             est = self.estimate_bytes(eqn)
             if est > self.limit_bytes:
                 out.append(Violation(
@@ -407,12 +419,10 @@ class VmemBudget(Rule):
             if not self.check_alignment:
                 continue
             gm = eqn.params["grid_mapping"]
-            try:
-                fulls = [jax.ShapeDtypeStruct(s.shape, s.dtype)
-                         for s in tuple(gm.in_shapes) + tuple(gm.out_shapes)]
-                blocks = [tuple(bm.block_shape) for bm in gm.block_mappings]
-            except Exception:      # unknown jax internals: skip, don't crash
-                continue
+            fulls = [jax.ShapeDtypeStruct(s.shape, s.dtype)
+                     for s in tuple(gm.in_shapes) + tuple(gm.out_shapes)]
+            blocks = [tuple(self._block_dim(d) for d in bm.block_shape)
+                      for bm in gm.block_mappings]
             for block, full in zip(blocks, fulls):
                 msg = self._aligned(block, full.shape, full.dtype)
                 if msg:
@@ -422,6 +432,22 @@ class VmemBudget(Rule):
                         path=path + ("pallas_call",),
                         shape=block, dtype=jnp.dtype(full.dtype).name))
         return out
+
+
+_MOSAIC_CALL = re.compile(
+    r'%([A-Za-z_][\w-]*?)(?:\.\d+)? = .*custom_call_target="tpu_custom_call"')
+
+
+def mosaic_kernels(compiled_text: str) -> collections.Counter:
+    """Kernels Mosaic compiled into a TPU executable, by ``pallas_call``
+    name, from ``jax.jit(f).lower(...).compile().as_text()``.
+
+    Each compiled kernel is one ``tpu_custom_call`` instruction named after
+    its ``pallas_call``.  A kernel run in interpret mode lowers to plain HLO
+    and leaves no such instruction, so a program whose kernels were quietly
+    interpreted counts none here.
+    """
+    return collections.Counter(_MOSAIC_CALL.findall(compiled_text))
 
 
 def check_rules(program: Program, rules) -> list:
@@ -436,5 +462,5 @@ __all__ = [
     "CALLBACK_PRIMS", "VMEM_BYTES", "Violation", "Program", "Rule",
     "NoStateTensor", "MaxScans", "MaxPallasCalls", "NoDtypeAbove",
     "NoSilentUpcast", "NoHostCallback", "DonationHonored", "VmemBudget",
-    "check_rules", "count_scans", "count_pallas_calls",
+    "check_rules", "count_scans", "count_pallas_calls", "mosaic_kernels",
 ]

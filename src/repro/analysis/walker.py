@@ -31,10 +31,7 @@ import dataclasses
 
 import jax
 
-try:  # jax >= 0.4.14
-    from jax.extend import core as jax_core
-except ImportError:  # pragma: no cover - older jax
-    from jax import core as jax_core
+from jax.extend import core as jax_core
 
 
 def _sub_jaxprs(params):

@@ -1,98 +1,52 @@
-"""jax version-compatibility shims.
+"""Mesh helpers over jax's public mesh API (jax >= 0.9).
 
-The mesh-context API was reworked between jax 0.4.x and 0.5+/0.6+:
+Everything in the repo that touches a mesh context goes through this module,
+so the call sites share one definition of "is a mesh active" and one way to
+activate one:
 
-* ``jax.sharding.get_abstract_mesh`` — public in newer jax (returns an empty
-  ``AbstractMesh`` when no mesh is set); 0.4.x keeps it in ``jax._src.mesh``
-  and returns ``()`` when unset.
-* ``AbstractMesh`` — newer jax takes ``(axis_sizes, axis_names)``; 0.4.x
-  takes a single ``((name, size), ...)`` shape tuple.
-* ``jax.make_mesh`` — newer jax accepts ``axis_types=``; 0.4.x does not.
-* ``jax.set_mesh`` — newer jax's context manager that sets both the concrete
-  and abstract mesh; 0.4.x only supports entering the ``Mesh`` itself (which
-  sets the thread-resources physical mesh).
-
-Everything in the repo that touches a mesh context goes through this module
-so the codebase runs unmodified on the installed jax (0.4.37) and on newer
-releases.  No other module should import from ``jax._src``.
+* ``get_abstract_mesh`` — the active mesh, or ``None`` (jax returns an
+  *empty* ``AbstractMesh`` when no mesh is set; callers here test ``None``);
+* ``abstract_mesh`` / ``make_mesh`` — mesh constructors with Auto axis types;
+* ``shardings_for`` — ``PartitionSpec`` pytrees to ``NamedSharding``;
+* ``use_mesh`` — ``jax.set_mesh``, which sets the concrete and the abstract
+  mesh for its dynamic extent.
 """
 
 from __future__ import annotations
 
-import contextlib
-
 import jax
-from jax.sharding import AbstractMesh, Mesh
+from jax.sharding import AbstractMesh, AxisType, Mesh, NamedSharding, PartitionSpec
 
 
 def get_abstract_mesh():
-    """The mesh of the current mesh context, or ``None`` when there is none.
-
-    Unlike newer jax's ``jax.sharding.get_abstract_mesh`` this never returns
-    an *empty* mesh — callers can test ``mesh is None`` only.  Under 0.4.x a
-    plain ``with mesh:`` context is also picked up (via the thread-resources
-    physical mesh), so ``use_mesh`` works uniformly across versions.
-    """
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is None:
-        from jax._src import mesh as _mesh_lib
-
-        fn = getattr(_mesh_lib, "get_abstract_mesh", lambda: None)
-    mesh = fn()
-    if isinstance(mesh, (AbstractMesh, Mesh)) and not mesh.empty:
-        return mesh
-    # jax 0.4.x: `with mesh:` populates thread resources, not the abstract
-    # mesh context; fall back to the physical mesh so maybe_shard & co. see
-    # the active mesh on old releases too.
-    try:
-        from jax._src import mesh as _mesh_lib
-
-        phys = _mesh_lib.thread_resources.env.physical_mesh
-    except Exception:  # pragma: no cover - future jax may drop thread_resources
-        return None
-    if phys is not None and not phys.empty:
-        return phys
-    return None
+    """The mesh of the current mesh context, or ``None`` when there is none."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def abstract_mesh(axis_sizes, axis_names) -> AbstractMesh:
-    """``AbstractMesh`` from parallel size/name tuples, on any jax version."""
+    """``AbstractMesh`` from parallel size/name tuples."""
     axis_sizes = tuple(int(s) for s in axis_sizes)
     axis_names = tuple(axis_names)
     if len(axis_sizes) != len(axis_names):
         raise ValueError(f"{axis_sizes=} vs {axis_names=}")
-    try:
-        return AbstractMesh(axis_sizes, axis_names)  # jax >= 0.5
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))  # jax 0.4.x
-
-
-def axis_types_auto(n: int):
-    """``(AxisType.Auto,) * n`` where supported, else ``None`` (0.4.x)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    return None if axis_type is None else (axis_type.Auto,) * n
+    return AbstractMesh(axis_sizes, axis_names)
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types where the kwarg exists."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    types = axis_types_auto(len(tuple(axis_names)))
-    if types is not None:
-        kwargs["axis_types"] = types
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` with Auto axis types."""
+    axis_names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), axis_names, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def shardings_for(mesh, tree):
     """Resolve a pytree of ``PartitionSpec`` into ``NamedSharding`` on ``mesh``.
 
-    Newer jax lets ``jax.jit(in_shardings=...)`` take bare specs when a mesh
-    is set; 0.4.x insists on ``Sharding`` objects.  Explicit ``NamedSharding``
-    works everywhere, so jit call sites route their spec trees through here.
+    jit call sites that build their spec trees before a mesh is active route
+    them through here, so ``in_shardings``/``out_shardings`` never depend on
+    the ambient mesh context.
     """
-    from jax.sharding import NamedSharding, PartitionSpec
-
     return jax.tree.map(
         lambda s: NamedSharding(mesh, s) if isinstance(s, PartitionSpec) else s,
         tree,
@@ -100,14 +54,6 @@ def shardings_for(mesh, tree):
     )
 
 
-@contextlib.contextmanager
 def use_mesh(mesh: Mesh):
-    """Activate ``mesh`` for the dynamic extent: ``jax.set_mesh`` on newer
-    jax, ``with mesh:`` (thread-resources) on 0.4.x."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        with set_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+    """Activate ``mesh`` for the dynamic extent of a ``with`` block."""
+    return jax.set_mesh(mesh)

@@ -117,6 +117,20 @@ def _states_fast_p(model, p, u: jnp.ndarray, s0: jnp.ndarray) -> jnp.ndarray:
     return jnp.moveaxis(states, 0, 1)
 
 
+def _kernel_states(model, j, mask, s0, *, block_s, return_final, state_dtype):
+    """The Pallas ``dfr_scan`` over [B, K] inputs, one launch per device
+    shard of B under an active mesh (parallel/sharding.over_batch_shards)."""
+    from repro.kernels.dfr_scan import ops as dfr_ops
+    from repro.parallel.sharding import over_batch_shards
+
+    def scan(jb, m, s):
+        return dfr_ops.dfr_scan(model, jb, m, s, block_s=block_s,
+                                return_final=return_final,
+                                out_dtype=state_dtype)
+
+    return over_batch_shards(scan, (j, mask, s0), (True, mask.ndim == 2, True))
+
+
 def generate_states(
     model: NLModel,
     j: jnp.ndarray,
@@ -170,11 +184,8 @@ def generate_states(
                 "dev_params (traced per-lane device parameters) are not "
                 "supported on the Pallas kernel path; sweep with "
                 "method='fast' or 'ref' (ROADMAP: swept-params kernel tiles)")
-        from repro.kernels.dfr_scan import ops as dfr_ops
-
-        out = dfr_ops.dfr_scan(model, jb, mask, s0b, block_s=block_s,
-                               return_final=return_final,
-                               out_dtype=state_dtype)
+        out = _kernel_states(model, jb, mask, s0b, block_s=block_s,
+                             return_final=return_final, state_dtype=state_dtype)
         states, s_final = out if return_final else (out, None)
     else:
         u = masked_input(jb, mask)
@@ -229,11 +240,8 @@ def generate_channel_states(
     s0 = jnp.asarray(s0, j.dtype)
 
     if method == "kernel":
-        from repro.kernels.dfr_scan import ops as dfr_ops
-
-        return dfr_ops.dfr_scan(model, j, masks, s0, block_s=block_s,
-                                return_final=return_final,
-                                out_dtype=state_dtype)
+        return _kernel_states(model, j, masks, s0, block_s=block_s,
+                              return_final=return_final, state_dtype=state_dtype)
 
     def one(jr, mr, s0r):
         return generate_states(model, jr, mr, s0=s0r, method=method,

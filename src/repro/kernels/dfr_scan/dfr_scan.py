@@ -54,6 +54,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import VMEM_LIMIT_BYTES
+
 LANES = 128
 
 
@@ -151,5 +153,7 @@ def dfr_scan_tiled(
             pltpu.VMEM((n_nodes, block_s, lanes), jnp.float32),
             pltpu.VMEM((block_s, lanes), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="dfr_scan",
     )(j, mask, s0)

@@ -45,6 +45,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import VMEM_LIMIT_BYTES
+
+_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def _dot_t(a, b):
+    """aᵀ @ b over the T tile, f32 accumulation.  f32 operands ask for the
+    full-f32 MXU contraction (bf16 passes would round the Gram statistics
+    the eigh solve depends on); bf16 operands multiply natively."""
+    precision = jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    return jax.lax.dot_general(a, b, dimension_numbers=(((0,), (0,)), ((), ())),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
 
 def _kernel(n_t_tiles, has_init, *refs):
     if has_init:
@@ -68,11 +82,7 @@ def _kernel(n_t_tiles, has_init, *refs):
             c_acc[...] = jnp.zeros_like(c_acc)
 
     xl = xl_ref[0]
-    g_acc[...] += jax.lax.dot_general(
-        xl, xr_ref[0],
-        dimension_numbers=(((0,), (0,)), ((), ())),  # xlᵀ @ xr, contraction over T
-        preferred_element_type=jnp.float32,
-    )
+    g_acc[...] += _dot_t(xl, xr_ref[0])
 
     # bf16 state chunks keep the target stream f32 (it is O(B·T), not worth
     # rounding); dot_general needs homogeneous operands, so upcast the lhs
@@ -80,11 +90,7 @@ def _kernel(n_t_tiles, has_init, *refs):
     @pl.when(j == 0)
     def _moment():
         xl_m = xl if xl.dtype == y_ref.dtype else xl.astype(y_ref.dtype)
-        c_acc[...] += jax.lax.dot_general(
-            xl_m, y_ref[0],
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        c_acc[...] += _dot_t(xl_m, y_ref[0])
 
     @pl.when(t == n_t_tiles - 1)
     def _flush_g():
@@ -139,7 +145,9 @@ def gram_tiled_batched(
             jax.ShapeDtypeStruct((batch, f_total, c_cols), jnp.float32),
         ],
         scratch_shapes=scratch,
+        compiler_params=_PARAMS,
         interpret=interpret,
+        name="ridge_gram",
     )(x, x, y)
 
 
@@ -181,7 +189,9 @@ def gram_tiled_batched_into(
         ],
         scratch_shapes=scratch,
         input_output_aliases={0: 0, 1: 1},
+        compiler_params=_PARAMS,
         interpret=interpret,
+        name="ridge_gram_into",
     )(g0.astype(jnp.float32), c0.astype(jnp.float32), x, x, y)
 
 

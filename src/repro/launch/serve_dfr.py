@@ -58,6 +58,7 @@ import numpy as np
 from repro.checkpoint import CheckpointStore
 from repro.core import tasks
 from repro.core.masking import make_mask
+from repro.launch.compile_cache import enable_compile_cache
 from repro.pipeline.session import (SessionConfig, _session_step,
                                     session_init)
 from repro.robustness.faults import FaultSpec, faulty_session_step
@@ -337,6 +338,45 @@ class DFRServer:
         self.close()
 
 
+def chan_eq_requests(n: int, stream_len: int, chunk: int, *,
+                     snr_db: float = 24.0, seed: int = 0) -> list[StreamRequest]:
+    """``n`` independent channel-equalization streams, one link each.
+
+    Lengths are cut to whole chunks so the per-session washout counter
+    tracks real periods exactly.  Same input layer as the Experiment
+    pipeline: a per-stream affine map to [0, 1] — the masked drive of the
+    silicon MR is an optical intensity and cannot go negative.
+    """
+    k = (stream_len // chunk) * chunk
+    out = []
+    for r in range(n):
+        ds = tasks.channel_equalization(max(k, 64), snr_db=snr_db,
+                                        train_frac=0.999, seed=seed + r)
+        x = np.asarray(ds.inputs_train[:k], np.float32)
+        x = (x - x.min()) / (x.max() - x.min() + 1e-12)
+        out.append(StreamRequest(
+            rid=r, j=x, y=np.asarray(ds.targets_train[:k], np.float32)))
+    return out
+
+
+def online_ser(completed: list[StreamRequest], washout: int):
+    """(online SER, steady-state SER) over completed streams: post-washout
+    4-PAM symbol errors of the session's own predictions, and the same over
+    each stream's last quarter, once the readout has converged."""
+    sers, sers_tail = [], []
+    sym = np.asarray(tasks.SYMBOLS, np.float32)
+    for req in completed:
+        yh = np.concatenate(req.y_hat)[washout:]
+        yt = req.y[washout:len(req.j)]
+        dec = sym[np.argmin(np.abs(yh[:, None] - sym[None, :]), axis=1)]
+        sers.append(float(np.mean(dec != yt)))
+        q = len(dec) // 4
+        sers_tail.append(float(np.mean(dec[-q:] != yt[-q:])))
+    nan = float("nan")
+    return (float(np.mean(sers)) if sers else nan,
+            float(np.mean(sers_tail)) if sers_tail else nan)
+
+
 def _latency_quantiles(seconds: list[float]):
     if not seconds:  # e.g. resumed from an already-drained checkpoint
         return float("nan"), float("nan")
@@ -367,6 +407,7 @@ def main(argv=None):
                     metavar=("LO", "HI"),
                     help="clamp finite host inputs to [LO, HI] at ingest")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = SessionConfig(n_nodes=args.nodes, washout=args.washout,
                         chunk_k=args.chunk, forgetting=args.forgetting,
@@ -384,39 +425,18 @@ def main(argv=None):
         if got is not None:
             print(f"resumed from checkpoint tick={got}")
 
-    # requests: independent channel-equalization streams (one link each),
-    # lengths padded to whole chunks so the per-session washout counter
-    # tracks real periods exactly.  Same input layer as the Experiment
-    # pipeline: per-stream affine map to [0, 1] — the masked drive of the
-    # silicon MR is an optical intensity and cannot go negative.
     if server.restored_from is None:
-        k = (args.stream_len // args.chunk) * args.chunk
-        for r in range(args.requests):
-            ds = tasks.channel_equalization(
-                max(k, 64), snr_db=args.snr_db, train_frac=0.999,
-                seed=args.seed + r)
-            x = np.asarray(ds.inputs_train[:k], np.float32)
-            x = (x - x.min()) / (x.max() - x.min() + 1e-12)
-            server.submit(StreamRequest(
-                rid=r, j=x, y=np.asarray(ds.targets_train[:k], np.float32)))
+        for req in chan_eq_requests(args.requests, args.stream_len, args.chunk,
+                                    snr_db=args.snr_db, seed=args.seed):
+            server.submit(req)
 
     t0 = time.perf_counter()
     server.drain()
     wall = time.perf_counter() - t0
 
-    # online quality: post-washout symbol error per completed stream, plus
-    # the steady-state (last-quarter) error once the readout has converged —
-    # the overall number includes the unavoidable cold-start misses made
-    # while the Gram was still filling
-    sers, sers_tail = [], []
-    sym = np.asarray(tasks.SYMBOLS, np.float32)
-    for req in server.completed:
-        yh = np.concatenate(req.y_hat)[args.washout:]
-        yt = req.y[args.washout:len(req.j)]
-        dec = sym[np.argmin(np.abs(yh[:, None] - sym[None, :]), axis=1)]
-        sers.append(float(np.mean(dec != yt)))
-        q = len(dec) // 4
-        sers_tail.append(float(np.mean(dec[-q:] != yt[-q:])))
+    # the overall SER includes the unavoidable cold-start misses made while
+    # the Gram was still filling; the steady-state one does not
+    ser, ser_tail = online_ser(server.completed, args.washout)
     p50, p99 = _latency_quantiles(server.tick_seconds)
     streams_per_s = len(server.completed) / max(wall, 1e-9)
     periods_per_s = sum(len(r.j) for r in server.completed) / max(wall, 1e-9)
@@ -424,8 +444,7 @@ def main(argv=None):
           f"ticks={server.tick} wall={wall*1e3:.1f}ms "
           f"({streams_per_s:.1f} streams/s, {periods_per_s:.0f} periods/s) "
           f"tick p50={p50:.0f}us p99={p99:.0f}us "
-          f"online-SER={np.mean(sers) if sers else float('nan'):.4f} "
-          f"steady-SER={np.mean(sers_tail) if sers_tail else float('nan'):.4f} "
+          f"online-SER={ser:.4f} steady-SER={ser_tail:.4f} "
           f"stats={json.dumps(server.stats())}")
     return server
 

@@ -256,3 +256,26 @@ def maybe_shard(x, *spec_entries):
     entries = [keep(e) for e in spec_entries]
     entries += [None] * (x.ndim - len(entries))
     return jax.lax.with_sharding_constraint(x, P(*entries))
+
+
+def over_batch_shards(fn, args, batched):
+    """``fn(*args)``, run once per device shard of the leading batch axis.
+
+    XLA cannot partition a Pallas kernel: under an active mesh Mosaic
+    refuses it ("Mosaic kernels cannot be automatically partitioned"), so
+    the kernel dispatches go through ``jax.shard_map`` and each device runs
+    the kernel on its own rows.  ``batched[i]`` says whether ``args[i]``
+    leads with the batch axis (sharded) or is shared by every row
+    (replicated, e.g. a broadcast mask); every output leads with the batch
+    axis.  The batch shards over the ("pod", "data") mesh axes that divide
+    it; when none does, every device runs the whole batch.  With no mesh
+    active this is plain ``fn(*args)``.
+    """
+    mesh = get_abstract_mesh()
+    if mesh is None:
+        return fn(*args)
+    batch = next(a.shape[0] for a, b in zip(args, batched) if b)
+    spec = P(batch_axes(mesh, batch=batch) or None)
+    in_specs = tuple(spec if b else P() for b in batched)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=spec,
+                         check_vma=False)(*args)

@@ -334,6 +334,7 @@ def _eval_streaming(cfg: ExperimentConfig, mask, j_te, te_tg3, w_fit, s0, *,
                                     state_dtype=cfg._stream_state_dtype_arg,
                                     dev_params=dev_params)
         y_hat = jnp.einsum("btf,bfc->btc", with_bias(states), w_fit,
+                           precision=jax.lax.Precision.HIGHEST,
                            preferred_element_type=jnp.float32)
         tidx = t_start + jnp.arange(chunk_k, dtype=jnp.int32)
         valid = (tidx < t_total).astype(jnp.float32)[None, :, None]

@@ -40,7 +40,12 @@ import jax.numpy as jnp
 
 from repro.core.graph import ReservoirGraph, stage_link_drive, stage_states
 from repro.core.reservoir import generate_channel_states, generate_states
-from repro.parallel.sharding import maybe_shard
+from repro.parallel.sharding import maybe_shard, over_batch_shards
+
+# The readout's f32 linear algebra asks for full f32 matmuls: at the default
+# precision a TPU multiplies f32 operands in one bf16 pass, which loses ~3
+# digits of the Gram statistics and of the eigenbasis products of the solve.
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def with_bias(states: jnp.ndarray) -> jnp.ndarray:
@@ -61,7 +66,8 @@ def gram(x: jnp.ndarray, y: jnp.ndarray, *, use_kernel: bool = False):
         return gram_ops.gram_accumulate(x, y)
     x32 = maybe_shard(x.astype(jnp.float32), ("pod", "data"))
     y32 = maybe_shard(y.astype(jnp.float32), ("pod", "data"))
-    return x32.T @ x32, x32.T @ y32
+    return (jnp.matmul(x32.T, x32, precision=_F32),
+            jnp.matmul(x32.T, y32, precision=_F32))
 
 
 def solve_gcv(
@@ -82,7 +88,7 @@ def solve_gcv(
     c32 = c.astype(jnp.float32)
     evals, q = jnp.linalg.eigh(g32)              # λᵢ ascending; tiny negatives
     evals = jnp.maximum(evals, 0.0)              # from f32 round-off -> clamp
-    qc = q.T @ c32                               # [F, C]
+    qc = jnp.matmul(q.T, c32, precision=_F32)    # [F, C]
     # Rank truncation: eigenvalues below f32 noise are not signal — keeping
     # them poisons both w (1/λᵢ blow-up) and the residual (the stray qc
     # energy in a null direction enters as qc²/λ′).  The 4·eps·λmax cutoff
@@ -97,7 +103,7 @@ def solve_gcv(
 
     def per_lambda(lam):
         inv = jnp.where(valid, 1.0 / (evals + lam), 0.0)   # [F]
-        w = q @ (qc * inv[:, None])              # [F, C]
+        w = jnp.matmul(q, qc * inv[:, None], precision=_F32)   # [F, C]
         dof = jnp.sum(evals * inv)
         # ‖y − ŷ‖² = ‖y‖² − Σᵢ qcᵢ²·(λᵢ + 2λ′)/(λᵢ + λ′)²  — evaluated in
         # the eigenbasis; the naive y2 − 2cᵀw + wᵀGw cancels catastrophically
@@ -128,7 +134,7 @@ def solve_gcv_svd(
     x32 = x.astype(jnp.float32)
     y32 = y.astype(jnp.float32)
     u, s, vt = jnp.linalg.svd(x32, full_matrices=False)   # [T,F], [F], [F,F]
-    uty = u.T @ y32                                       # [F, C]
+    uty = jnp.matmul(u.T, y32, precision=_F32)            # [F, C]
     uy2 = jnp.sum(uty * uty, axis=1)                      # [F]
     y2 = jnp.sum(y32 * y32)
     s2 = s * s
@@ -137,7 +143,8 @@ def solve_gcv_svd(
 
     def per_lambda(lam):
         shrink = s2 / (s2 + lam)                          # [F]
-        w = vt.T @ (uty * (s / (s2 + lam))[:, None])      # [F, C]
+        w = jnp.matmul(vt.T, uty * (s / (s2 + lam))[:, None],
+                       precision=_F32)                    # [F, C]
         dof = jnp.sum(shrink)
         rss = jnp.maximum(y2 - jnp.sum((2.0 * shrink - shrink * shrink) * uy2), 0.0)
         gcv = n_samples * rss / jnp.maximum(n_samples - dof, 1.0) ** 2
@@ -194,8 +201,10 @@ def fit_ridge_batched(
         from repro.kernels.ridge_gram import ops as gram_ops
 
         x = with_bias(states)
-        g, c = gram_ops.gram_accumulate_batched(x, y.astype(x.dtype),
-                                                block_t=block_t)
+        g, c = over_batch_shards(
+            functools.partial(gram_ops.gram_accumulate_batched,
+                              block_t=block_t),
+            (x, y.astype(x.dtype)), (True, True))
         y32 = y.astype(jnp.float32)
         y2 = jnp.sum(y32 * y32, axis=(1, 2))
         n_samples = x.shape[1]
@@ -225,7 +234,7 @@ def guard_readout(w_new: jnp.ndarray, idx_new: jnp.ndarray,
 
 def apply_readout(states: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     """y = [states, 1] @ w; squeezes a single output channel."""
-    y = with_bias(states) @ w
+    y = jnp.matmul(with_bias(states), w, precision=_F32)
     return y[..., 0] if y.shape[-1] == 1 else y
 
 
@@ -326,13 +335,14 @@ def _fold_chunk(plan: _FoldPlan, g, cvec, y2, x, yv, *, forgetting: float = 1.0)
         xq = jnp.pad(x, ((0, 0), (0, plan.chunk_pt - plan.chunk_k),
                          (0, plan.fq - plan.f)))
         yq = jnp.pad(yv, ((0, 0), (0, plan.chunk_pt - plan.chunk_k), (0, 0)))
-        g, cvec = gram_tiled_batched_into(g, cvec, xq, yq, block_t=plan.eff_bt,
-                                          block_f=plan.block_f,
-                                          interpret=plan.interpret)
+        fold = functools.partial(gram_tiled_batched_into, block_t=plan.eff_bt,
+                                 block_f=plan.block_f,
+                                 interpret=plan.interpret)
+        g, cvec = over_batch_shards(fold, (g, cvec, xq, yq), (True,) * 4)
     else:
-        g = g + jnp.einsum("btf,btg->bfg", x, x,
+        g = g + jnp.einsum("btf,btg->bfg", x, x, precision=_F32,
                            preferred_element_type=jnp.float32)
-        cvec = cvec + jnp.einsum("btf,btc->bfc", x, yv,
+        cvec = cvec + jnp.einsum("btf,btc->bfc", x, yv, precision=_F32,
                                  preferred_element_type=jnp.float32)
     return g, cvec, y2
 

@@ -336,6 +336,7 @@ def session_predict(cfg: SessionConfig, mask: jnp.ndarray, state: SessionState,
     """
     states, s_next = _gen_chunk(cfg, mask, j_chunk, state.s)
     y_hat = jnp.einsum("btf,bfc->btc", with_bias(states), state.w,
+                       precision=jax.lax.Precision.HIGHEST,
                        preferred_element_type=jnp.float32)
     return y_hat, state._replace(s=s_next,
                                  step=state.step + jnp.int32(cfg.chunk_k))
@@ -370,6 +371,7 @@ def _session_step(cfg: SessionConfig, mask: jnp.ndarray, state: SessionState,
     y3 = _canon_chunk_targets(cfg, y_chunk)
     states, s_next = _gen_chunk(cfg, mask, j_chunk, state.s)
     y_hat = jnp.einsum("btf,bfc->btc", with_bias(states), state.w,
+                       precision=jax.lax.Precision.HIGHEST,
                        preferred_element_type=jnp.float32)
     vfit = _valid_mask(cfg, state.step, n_valid)
     state = _fold(cfg, state, states, y3, vfit, s_next)

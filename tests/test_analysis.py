@@ -19,8 +19,9 @@ import pytest
 from repro.analysis import (DonationHonored, MaxPallasCalls, MaxScans,
                             NoDtypeAbove, NoHostCallback, NoSilentUpcast,
                             NoStateTensor, Program, VmemBudget,
-                            intermediate_records, state_tensor_bytes,
-                            state_tensor_records, trace_jaxpr)
+                            intermediate_records, mosaic_kernels,
+                            state_tensor_bytes, state_tensor_records,
+                            trace_jaxpr)
 from repro.analysis.walker import _sub_jaxprs
 
 # ---------------------------------------------------------------------------
@@ -180,7 +181,7 @@ def test_rule_no_dtype_above_catches_f64_literal():
     def prog(x):
         return x * np.float64(2.0) + jnp.asarray(1.0, jnp.float64)
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         viols = NoDtypeAbove("float32").check(
             Program(prog, (jnp.ones((4,), jnp.float32),)))
     assert viols and all(v.dtype == "float64" for v in viols)
@@ -209,7 +210,7 @@ def test_rule_no_host_callback_with_provenance():
 
     viols = NoHostCallback().check(
         Program(prog_print, (jnp.ones((4,), jnp.float32),)))
-    assert viols and "debug_callback" in viols[0].message
+    assert viols and "debug_print" in viols[0].message
 
 
 def test_rule_donation_honored_detects_dropped_alias():
@@ -271,11 +272,12 @@ def _copy_kernel_program(shape, dtype, block):
 
 
 def test_rule_vmem_budget_overflow():
-    # one 8 MiB f32 block, double-buffered in+out = 32 MiB > 16 MiB budget
-    prog = _copy_kernel_program((2048, 1024), jnp.float32, (2048, 1024))
+    # one 32 MiB f32 block, double-buffered in+out = 128 MiB > the 64 MiB
+    # scoped-VMEM limit the kernels compile with
+    prog = _copy_kernel_program((4096, 2048), jnp.float32, (4096, 2048))
     viols = VmemBudget().check(prog)
     assert viols and "VMEM" in viols[0].message
-    assert not VmemBudget(limit_bytes=64 * 2 ** 20).check(prog)
+    assert not VmemBudget(limit_bytes=256 * 2 ** 20).check(prog)
 
 
 def test_rule_vmem_alignment_sub_f32_multi_tile():
@@ -292,6 +294,17 @@ def test_rule_vmem_alignment_sub_f32_multi_tile():
     assert not VmemBudget().check(
         _copy_kernel_program((32, 256), jnp.float32, (4, 256)))
     assert not VmemBudget(check_alignment=False).check(bad)
+
+
+def test_mosaic_kernels_counts_only_compiled_kernels():
+    """An interpreted kernel lowers to plain HLO: the compiled program then
+    holds no ``tpu_custom_call`` and ``mosaic_kernels`` finds nothing."""
+    prog = _copy_kernel_program((16, 128), jnp.float32, (8, 128))
+    text = jax.jit(prog.fn).lower(*prog.args).compile().as_text()
+    assert mosaic_kernels(text) == {}
+    line = ('  %dfr_scan.3 = (f32[8,4,128]{2,1,0}) custom-call(f32[8,4,128] '
+            '%p0), custom_call_target="tpu_custom_call", backend_config={}')
+    assert mosaic_kernels(line + "\n" + line) == {"dfr_scan": 2}
 
 
 # ---------------------------------------------------------------------------

@@ -19,6 +19,8 @@ pytestmark = pytest.mark.multidev
 def _run(src: str):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # children must never reach for an accelerator the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = "src"
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(src)],
                          capture_output=True, text=True, env=env,
@@ -55,7 +57,6 @@ def test_pipeline_matches_sequential():
 def test_compressed_psum_error_feedback():
     _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.optim.compression import compressed_psum
 
@@ -67,8 +68,8 @@ def test_compressed_psum_error_feedback():
         def sync(g_local, err):
             return compressed_psum(g_local[0], err[0], "pod")
 
-        fn = shard_map(sync, mesh=mesh, in_specs=(P("pod"), P("pod")),
-                       out_specs=(P(), P("pod")), check_rep=False)
+        fn = jax.shard_map(sync, mesh=mesh, in_specs=(P("pod"), P("pod")),
+                           out_specs=(P(), P("pod")), check_vma=False)
         err0 = jnp.zeros((8, 64, 37))
         g_hat, err = fn(g, err0)
         err = err.reshape(8, 64, 37)                # out_specs stacks shards
