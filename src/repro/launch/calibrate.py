@@ -21,8 +21,7 @@ solve for the per-component costs exactly:
 
 Known residual under-counts (inner ``while`` loops inside one unit body,
 counted once per body): sLSTM's sequence scan, the ReservoirMixer period
-scan, and the chunked-attention KV scan.  benchmarks/roofline.py adds
-documented analytic corrections for these.
+scan, and the chunked-attention KV scan.
 
 Writes experiments/dryrun/calib__<arch>__<shape>__pod.json.
 """

@@ -1,8 +1,7 @@
 """Production meshes (assignment spec).
 
 Defined as functions, not module constants, so importing this module never
-touches jax device state.  TPU v5e class constants for the roofline live in
-benchmarks/roofline.py.
+touches jax device state.
 """
 
 from __future__ import annotations

@@ -50,6 +50,7 @@ from repro.core.reservoir import generate_channel_states, generate_states
 from repro.core.tasks import SYMBOLS
 from repro.parallel.sharding import maybe_shard
 
+from . import scopes
 from .ridge import (apply_readout, composed_chunk_states_fn, fit_ridge_batched,
                     fit_ridge_streaming, fit_ridge_streaming_composed,
                     fit_ridge_streaming_shared, fit_ridge_streaming_wdm,
@@ -277,6 +278,7 @@ def _gen_states(cfg: ExperimentConfig, mask, j, *, wdm: bool, s0=None,
                            state_dtype=state_dtype, dev_params=dev_params)
 
 
+@scopes.scoped(scopes.EVAL)
 def _eval_streaming(cfg: ExperimentConfig, mask, j_te, te_tg3, w_fit, s0, *,
                     wdm: bool = False, states_fn=None, dev_params=None):
     """Chunked test evaluation: states per chunk, running error accumulators.
@@ -355,6 +357,7 @@ def _eval_streaming(cfg: ExperimentConfig, mask, j_te, te_tg3, w_fit, s0, *,
     return y_raw, acc
 
 
+@scopes.scoped(scopes.EVAL)
 def _streaming_metrics(acc, t_test: int, *, channel_axis: bool):
     """NRMSE/SER from the running accumulators — same conventions as the
     materialized path: per-channel NRMSE (that channel's variance, computed
@@ -398,15 +401,16 @@ def _run_pipeline(cfg: ExperimentConfig, mask, tr_in, tr_tg, te_in, te_tg,
     tuple); both are streaming-only (enforced at config construction).
     """
     # -- input layer: per-instance normalisation + sample-and-hold + gain ----
-    if cfg.normalize_input:
-        lo = jnp.min(tr_in, axis=1, keepdims=True)
-        scale = 1.0 / (jnp.max(tr_in, axis=1, keepdims=True) - lo + 1e-12)
-    else:
-        lo, scale = 0.0, 1.0
-    j_tr = sample_and_hold((tr_in - lo) * scale * cfg.input_gain)
-    j_te = sample_and_hold((te_in - lo) * scale * cfg.input_gain)
-    j_tr = maybe_shard(j_tr, ("pod", "data"))
-    j_te = maybe_shard(j_te, ("pod", "data"))
+    with jax.named_scope(scopes.INPUT):
+        if cfg.normalize_input:
+            lo = jnp.min(tr_in, axis=1, keepdims=True)
+            scale = 1.0 / (jnp.max(tr_in, axis=1, keepdims=True) - lo + 1e-12)
+        else:
+            lo, scale = 0.0, 1.0
+        j_tr = sample_and_hold((tr_in - lo) * scale * cfg.input_gain)
+        j_te = sample_and_hold((te_in - lo) * scale * cfg.input_gain)
+        j_tr = maybe_shard(j_tr, ("pod", "data"))
+        j_te = maybe_shard(j_te, ("pod", "data"))
 
     if cfg.stream_chunk_k is not None:
         # -- streaming fused path (DESIGN.md §8/§9/§13): reservoir chunks
@@ -451,9 +455,10 @@ def _run_pipeline(cfg: ExperimentConfig, mask, tr_in, tr_tg, te_in, te_tg,
                     j_c.shape[1], r * n_nodes)[None]
                 return feats, (s_next[None],)
 
-            y_raw3, acc = _eval_streaming(
-                cfg, mask, jnp.moveaxis(j_te, 0, 1)[None], te_tg3,
-                w_fit, (s_1[None],), states_fn=eval_fn)
+            with jax.named_scope(scopes.EVAL):
+                y_raw3, acc = _eval_streaming(
+                    cfg, mask, jnp.moveaxis(j_te, 0, 1)[None], te_tg3,
+                    w_fit, (s_1[None],), states_fn=eval_fn)
         elif dev_params is not None:
             w_fit, lam_idx, s_carry = fit_ridge_streaming(
                 cfg.model, mask, j_tr, tr_tg, dev_params=dev_params, **kw)
@@ -465,40 +470,48 @@ def _run_pipeline(cfg: ExperimentConfig, mask, tr_in, tr_tg, te_in, te_tg,
             w_fit, lam_idx, s_carry = fit(cfg.model, mask, j_tr, tr_tg, **kw)
             y_raw3, acc = _eval_streaming(cfg, mask, j_te, te_tg3,
                                           w_fit, s_carry, wdm=wdm)
-        nrmse, ser = _streaming_metrics(acc, te_tg3.shape[1],
-                                        channel_axis=te_tg.ndim == 3)
-        lam = jnp.asarray(cfg.ridge_l2, jnp.float32)[lam_idx]
-        if y_raw3 is None:
-            return None, nrmse, ser, lam, w_fit
-        y_raw = y_raw3 if te_tg.ndim == 3 else y_raw3[..., 0]
-        y_out = _quantize(y_raw) if cfg.quantize else y_raw
-        return y_out, nrmse, ser, lam, w_fit
+        with jax.named_scope(scopes.EVAL):
+            nrmse, ser = _streaming_metrics(acc, te_tg3.shape[1],
+                                            channel_axis=te_tg.ndim == 3)
+            lam = jnp.asarray(cfg.ridge_l2, jnp.float32)[lam_idx]
+            if y_raw3 is None:
+                return None, nrmse, ser, lam, w_fit
+            y_raw = y_raw3 if te_tg.ndim == 3 else y_raw3[..., 0]
+            y_out = _quantize(y_raw) if cfg.quantize else y_raw
+            return y_out, nrmse, ser, lam, w_fit
 
-    # -- reservoir layer: batched state generation, carry train -> test ------
-    st_tr, s_carry = _gen_states(cfg, mask, j_tr, wdm=wdm, return_final=True,
-                                 dev_params=dev_params)
-    st_te = _gen_states(cfg, mask, j_te, wdm=wdm, s0=s_carry,
-                        dev_params=dev_params)
-    st_tr = maybe_shard(st_tr, ("pod", "data"))
-    st_te = maybe_shard(st_te, ("pod", "data"))
+    with jax.named_scope(scopes.COLLECT):
+        # -- reservoir layer: batched state generation, carry train -> test --
+        st_tr, s_carry = _gen_states(cfg, mask, j_tr, wdm=wdm,
+                                     return_final=True, dev_params=dev_params)
+        st_te = _gen_states(cfg, mask, j_te, wdm=wdm, s0=s_carry,
+                            dev_params=dev_params)
+        st_tr = maybe_shard(st_tr, ("pod", "data"))
+        st_te = maybe_shard(st_te, ("pod", "data"))
 
-    # -- output layer: digitiser noise + per-instance ridge/GCV fit ----------
-    w = cfg.washout
-    st_fit = st_tr[:, w:]
-    y_fit = tr_tg[:, w:]
-    if cfg.state_noise_rel:
-        sigma = cfg.state_noise_rel * jnp.std(st_fit, axis=(1, 2), keepdims=True)
-        noise = jax.random.normal(jax.random.PRNGKey(cfg.noise_seed), st_fit.shape,
-                                  st_fit.dtype)
-        st_fit = st_fit + sigma * noise
+        # -- output layer: digitiser noise + per-instance ridge/GCV fit ------
+        w = cfg.washout
+        st_fit = st_tr[:, w:]
+        y_fit = tr_tg[:, w:]
+        if cfg.state_noise_rel:
+            sigma = cfg.state_noise_rel * jnp.std(st_fit, axis=(1, 2), keepdims=True)
+            noise = jax.random.normal(jax.random.PRNGKey(cfg.noise_seed),
+                                      st_fit.shape, st_fit.dtype)
+            st_fit = st_fit + sigma * noise
 
-    # Kernel path: ONE batch-gridded pallas_call over the instance stack
-    # (ridge.fit_ridge_batched); jnp path: vmapped SVD solve.
-    w_fit, lam_idx = fit_ridge_batched(
-        st_fit, y_fit, lambdas=cfg.ridge_l2,
-        use_kernel=cfg.readout_use_kernel, block_t=cfg.readout_block_t)
+        # Kernel path: ONE batch-gridded pallas_call over the instance stack
+        # (ridge.fit_ridge_batched); jnp path: vmapped SVD solve.
+        w_fit, lam_idx = fit_ridge_batched(
+            st_fit, y_fit, lambdas=cfg.ridge_l2,
+            use_kernel=cfg.readout_use_kernel, block_t=cfg.readout_block_t)
 
     # -- evaluation -----------------------------------------------------------
+    return _materialized_eval(cfg, st_te, te_tg, w_fit, lam_idx)
+
+
+@scopes.scoped(scopes.EVAL)
+def _materialized_eval(cfg: ExperimentConfig, st_te, te_tg, w_fit, lam_idx):
+    """Readout on the materialised test states, then NRMSE/SER."""
     y_raw = jax.vmap(apply_readout)(st_te, w_fit)      # [B, T_test(, C)]
     y_sym = _quantize(y_raw)
     inst_axes = tuple(range(1, y_raw.ndim))            # all but the batch axis
@@ -567,7 +580,35 @@ class Experiment:
         scalar or [B]) — the design-space-exploration hook (DESIGN.md §14):
         every lane runs the same compiled program at its own device point,
         and re-running with new parameter values recompiles nothing.
+
+        Under a profiler trace the call shows as three host spans
+        (``scopes.HOST_SPANS``): ``dfrc.prepare``, ``dfrc.dispatch`` and
+        ``dfrc.fetch``, the last holding the wait for the device.
         """
+        with jax.profiler.TraceAnnotation(scopes.PREPARE):
+            operands = self._operands(inputs_train, targets_train,
+                                      inputs_test, targets_test, dev_params)
+        with jax.profiler.TraceAnnotation(scopes.DISPATCH):
+            out = _run_pipeline(self.config, self.mask, *operands,
+                                dev_params=dev_params)
+        with jax.profiler.TraceAnnotation(scopes.FETCH):
+            return _pack_result(*out)
+
+    def lowered(self, inputs_train, targets_train, inputs_test, targets_test,
+                *, dev_params=None) -> jax.stages.Lowered:
+        """The program ``run`` calls for these inputs, lowered: its
+        ``compile()`` is the executable, whose ``as_text()`` is the optimised
+        HLO with each instruction's ``dfrc.*`` scope in its ``op_name`` and
+        whose ``memory_analysis()`` gives the device memory it takes.  Same
+        shapes as a ``run`` call: the compile is a cache hit."""
+        operands = self._operands(inputs_train, targets_train, inputs_test,
+                                  targets_test, dev_params)
+        return _run_pipeline.lower(self.config, self.mask, *operands,
+                                   dev_params=dev_params)
+
+    def _operands(self, inputs_train, targets_train, inputs_test, targets_test,
+                  dev_params):
+        """Canonicalised device operands (tr_in, tr_tg, te_in, te_tg)."""
         tr_in = _canon_batch(inputs_train, "inputs_train")
         te_in = _canon_batch(inputs_test, "inputs_test")
         tr_tg = _canon_targets(targets_train, "targets_train", tr_in)
@@ -594,10 +635,7 @@ class Experiment:
                     raise ValueError(
                         f"dev_params leaves must be scalars or [{b}] "
                         f"(one value per batch lane), got shape {arr.shape}")
-        y, nrmse, ser, lam, w = _run_pipeline(
-            self.config, self.mask, tr_in, tr_tg, te_in, te_tg,
-            dev_params=dev_params)
-        return _pack_result(y, nrmse, ser, lam, w)
+        return tr_in, tr_tg, te_in, te_tg
 
     def run_dataset(self, ds) -> ExperimentResult:
         """Convenience for a core.tasks Dataset (single instance, B = 1)."""

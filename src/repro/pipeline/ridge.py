@@ -42,6 +42,8 @@ from repro.core.graph import ReservoirGraph, stage_link_drive, stage_states
 from repro.core.reservoir import generate_channel_states, generate_states
 from repro.parallel.sharding import maybe_shard, over_batch_shards
 
+from . import scopes
+
 # The readout's f32 linear algebra asks for full f32 matmuls: at the default
 # precision a TPU multiplies f32 operands in one bf16 pass, which loses ~3
 # digits of the Gram statistics and of the eigenbasis products of the solve.
@@ -70,6 +72,7 @@ def gram(x: jnp.ndarray, y: jnp.ndarray, *, use_kernel: bool = False):
             jnp.matmul(x32.T, y32, precision=_F32))
 
 
+@scopes.scoped(scopes.SOLVE)
 def solve_gcv(
     g: jnp.ndarray,        # [F, F]
     c: jnp.ndarray,        # [F, C]
@@ -86,7 +89,8 @@ def solve_gcv(
     f = g.shape[0]
     g32 = g.astype(jnp.float32)
     c32 = c.astype(jnp.float32)
-    evals, q = jnp.linalg.eigh(g32)              # λᵢ ascending; tiny negatives
+    with jax.named_scope(scopes.EIGH):
+        evals, q = jnp.linalg.eigh(g32)          # λᵢ ascending; tiny negatives
     evals = jnp.maximum(evals, 0.0)              # from f32 round-off -> clamp
     qc = jnp.matmul(q.T, c32, precision=_F32)    # [F, C]
     # Rank truncation: eigenvalues below f32 noise are not signal — keeping
@@ -118,6 +122,7 @@ def solve_gcv(
     return ws[idx], idx
 
 
+@scopes.scoped(scopes.SOLVE)
 def solve_gcv_svd(
     x: jnp.ndarray,        # [T, F]
     y: jnp.ndarray,        # [T, C]
@@ -155,6 +160,7 @@ def solve_gcv_svd(
     return ws[idx], idx
 
 
+@scopes.scoped(scopes.SOLVE)
 def fit_ridge(
     states: jnp.ndarray,   # [T, N]
     targets: jnp.ndarray,  # [T] or [T, C]
@@ -178,6 +184,7 @@ def fit_ridge(
     return solve_gcv_svd(x, y, tuple(lambdas))
 
 
+@scopes.scoped(scopes.COLLECT)
 def fit_ridge_batched(
     states: jnp.ndarray,   # [B, T, N]
     targets: jnp.ndarray,  # [B, T] or [B, T, C]
@@ -520,6 +527,7 @@ def _fit_streaming_core(
     return w, idx, s_end
 
 
+@scopes.scoped(scopes.COLLECT)
 @functools.partial(jax.jit, static_argnames=(
     "model", "washout", "chunk_k", "lambdas", "state_method", "block_s",
     "use_kernel", "block_t", "block_f", "noise_rel", "state_dtype",
@@ -612,6 +620,7 @@ def fit_ridge_streaming(
         forgetting=forgetting)
 
 
+@scopes.scoped(scopes.COLLECT)
 @functools.partial(jax.jit, static_argnames=(
     "model", "washout", "chunk_k", "lambdas", "state_method", "block_s",
     "use_kernel", "block_t", "block_f", "noise_rel", "state_dtype",
@@ -713,6 +722,7 @@ def composed_chunk_states_fn(graph: ReservoirGraph, masks, *,
     return states_fn
 
 
+@scopes.scoped(scopes.COLLECT)
 @functools.partial(jax.jit, static_argnames=(
     "graph", "washout", "chunk_k", "lambdas", "state_method", "block_s",
     "use_kernel", "block_t", "block_f", "noise_rel", "state_dtype",
@@ -771,6 +781,7 @@ def fit_ridge_streaming_composed(
         carry_layout=graph.carry_layout)
 
 
+@scopes.scoped(scopes.COLLECT)
 @functools.partial(jax.jit, static_argnames=(
     "model", "washout", "chunk_k", "lambdas", "state_method", "block_s",
     "use_kernel", "block_t", "block_f", "noise_rel", "state_dtype",

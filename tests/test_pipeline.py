@@ -12,11 +12,13 @@ regression caught during development) or SER > 0.16, and a broken reservoir
 as NRMSE ≈ 1 / SER ≈ 0.75 (chance).
 """
 
+import re
+
 import numpy as np
 import pytest
 
 from repro.core import MZISine, MackeyGlass, SiliconMR, tasks
-from repro.pipeline import Experiment, ExperimentConfig
+from repro.pipeline import Experiment, ExperimentConfig, scopes
 
 LAMS = (1e-10, 1e-8, 1e-6, 1e-4, 1e-2)
 N_INSTANCES = 8
@@ -228,3 +230,86 @@ def test_mzi_and_mg_models_run_batched(narma_small_batch):
         res = Experiment(cfg).run(*narma_small_batch)
         assert np.all(np.isfinite(res.nrmse))
         assert np.all(res.nrmse < 1.1), res.nrmse
+
+
+def _hlo_scopes(text: str) -> dict:
+    """{instruction: (opcode, innermost dfrc.* scope or None)} of the
+    entry and loop computations of an optimised HLO module.  A fusion is
+    read through its fused root; an instruction that carries no metadata
+    at all (XLA made it) through the instructions that use it."""
+    comps, comp = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$", line)
+        if head:
+            comp = comps.setdefault(head.group(1), [])
+            continue
+        ins = re.match(r"^\s+(ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$", line)
+        if ins and comp is not None:
+            op = re.search(r"\s([a-z][\w\-]*)\(", ins.group(3))
+            comp.append((ins.group(2), op.group(1) if op else "", ins.group(3),
+                         bool(ins.group(1))))
+    own, root_of, users = {}, {}, {}
+    for name, instrs in comps.items():
+        for ins, _, rest, is_root in instrs:
+            path = re.search(r'op_name="([^"]*)"', rest)
+            found = re.findall(r"dfrc\.\w+", path.group(1)) if path else []
+            own[ins] = found[-1] if found else (None if path is None else "")
+            if is_root:
+                root_of[name] = ins
+            for operand in re.findall(r"%([\w.\-]+)", rest.split("), ")[0]):
+                users.setdefault(operand, []).append(ins)
+    calls = {ins: re.search(r"calls=%?([\w.\-]+)", rest).group(1)
+             for instrs in comps.values() for ins, op, rest, _ in instrs
+             if op == "fusion"}
+
+    def scope(ins, seen=()):
+        got = own.get(ins)
+        if ins in calls:
+            got = scope(root_of[calls[ins]], seen) or got
+        if got is None and ins not in seen:      # no metadata: XLA's own
+            for user in users.get(ins, ()):
+                got = got or scope(user, seen + (ins,))
+        return got or None
+
+    return {ins: (op, scope(ins)) for name, instrs in comps.items()
+            if not name.startswith(("fused", "wrapped")) for ins, op, _, _ in instrs}
+
+
+def test_lowered_program_names_its_phases(narma_small_batch):
+    """``Experiment.lowered`` is the streaming fit program with each phase
+    under its ``dfrc.*`` scope: all five appear in the optimised HLO, and
+    every fusion, custom-call, while and dot lies in one.  (XLA's CPU
+    tree-reduction rewrite adds reduce-window fusions with no metadata at
+    all; they count under the instruction they feed.)"""
+    cfg = ExperimentConfig(model=SiliconMR(), n_nodes=32, washout=40,
+                           ridge_l2=LAMS, state_method="kernel",
+                           readout_use_kernel=True, stream_chunk_k=64,
+                           state_noise_mode="diagonal")
+    text = Experiment(cfg).lowered(*narma_small_batch).compile().as_text()
+    got = _hlo_scopes(text)
+    assert {s for _, s in got.values()} >= set(scopes.DEVICE_SCOPES)
+    missing = [ins for ins, (op, s) in got.items()
+               if op in ("fusion", "custom-call", "while", "dot") and s is None]
+    assert not missing, missing
+
+
+def test_run_marks_its_host_spans(narma_small_batch, tmp_path):
+    """Under a profiler trace ``Experiment.run`` shows as its three host
+    spans, in order, on the profiler's host clock."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    exp = Experiment(ExperimentConfig(model=SiliconMR(), n_nodes=16, washout=40,
+                                      ridge_l2=(1e-4,)))
+    exp.run(*narma_small_batch)                      # compile outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        exp.run(*narma_small_batch)
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    events = sorted((ev.start_ns, ev.name)
+                    for plane in ProfileData.from_file(path).planes
+                    if plane.name.startswith("/host:")
+                    for line in plane.lines for ev in line.events
+                    if ev.name in scopes.HOST_SPANS)
+    assert [name for _, name in events] == list(scopes.HOST_SPANS)
