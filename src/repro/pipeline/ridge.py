@@ -42,7 +42,7 @@ from repro.core.graph import ReservoirGraph, stage_link_drive, stage_states
 from repro.core.reservoir import generate_channel_states, generate_states
 from repro.parallel.sharding import maybe_shard, over_batch_shards
 
-from . import scopes
+from . import qdwh_eigh, scopes
 
 # The readout's f32 linear algebra asks for full f32 matmuls: at the default
 # precision a TPU multiplies f32 operands in one bf16 pass, which loses ~3
@@ -72,6 +72,39 @@ def gram(x: jnp.ndarray, y: jnp.ndarray, *, use_kernel: bool = False):
             jnp.matmul(x32.T, y32, precision=_F32))
 
 
+# On the TPU an eigh of at most this many rows is XLA's Jacobi, natively
+# batched; a wider one is JAX's QDWH divide and conquer, which ``qdwh_eigh``
+# runs on fewer zeros
+_JACOBI_ROWS = 256
+
+
+def _lapack_eigh(g):
+    return tuple(jnp.linalg.eigh(g))
+
+
+@jax.custom_batching.custom_vmap
+def _eigh(g):
+    """``jnp.linalg.eigh`` of one Gram [F, F]: (eigenvalues ascending,
+    eigenvectors); wider than ``_JACOBI_ROWS`` on the TPU ``qdwh_eigh``'s,
+    one matrix at a time under ``vmap``."""
+    if g.shape[-1] <= _JACOBI_ROWS:
+        return _lapack_eigh(g)
+    return jax.lax.platform_dependent(g, tpu=qdwh_eigh.eigh, default=_lapack_eigh)
+
+
+@_eigh.def_vmap
+def _eigh_vmap(axis_size, in_batched, g):
+    if not in_batched[0]:
+        return _eigh(g), (False, False)
+    if g.shape[-1] <= _JACOBI_ROWS:
+        return jax.vmap(_lapack_eigh)(g), (True, True)
+    # a map of ``_eigh``, so a vmap around this one maps again; under a mesh
+    # each device maps its own shard of the batch
+    return jax.lax.platform_dependent(
+        g, tpu=lambda g: over_batch_shards(lambda g: jax.lax.map(_eigh, g), (g,), (True,)),
+        default=jax.vmap(_lapack_eigh)), (True, True)
+
+
 @scopes.scoped(scopes.SOLVE)
 def solve_gcv(
     g: jnp.ndarray,        # [F, F]
@@ -85,12 +118,15 @@ def solve_gcv(
     Returns (w [F, C], lam_idx) — ``lam_idx`` indexes the winning entry of
     the static ``lambdas`` tuple.  A single-element tuple skips nothing but
     costs one extra reduction; the eigendecomposition dominates either way.
+    On the TPU with F > 256 the eigendecomposition is ``qdwh_eigh``'s divide
+    and conquer, one matrix at a time under ``vmap`` (``_eigh``); else
+    ``jnp.linalg.eigh``.
     """
     f = g.shape[0]
     g32 = g.astype(jnp.float32)
     c32 = c.astype(jnp.float32)
     with jax.named_scope(scopes.EIGH):
-        evals, q = jnp.linalg.eigh(g32)          # λᵢ ascending; tiny negatives
+        evals, q = _eigh(g32)                    # λᵢ ascending; tiny negatives
     evals = jnp.maximum(evals, 0.0)              # from f32 round-off -> clamp
     qc = jnp.matmul(q.T, c32, precision=_F32)    # [F, C]
     # Rank truncation: eigenvalues below f32 noise are not signal — keeping

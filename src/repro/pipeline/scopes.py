@@ -16,6 +16,11 @@ solve called inside state collection counts as the solve, and the
 * ``dfrc.eigh``    -- the eigendecomposition inside ``solve_gcv``;
 * ``dfrc.eval``    -- the test evaluation and its metrics.
 
+Inside ``dfrc.eigh`` the divide and conquer (``qdwh_eigh``) names its two
+kinds of step, without the ``dfrc.`` prefix so that ``dfrc.eigh`` stays
+their innermost phase and its time counts them both: ``eigh_split`` (the
+QDWH splits) and ``eigh_leaf`` (the Jacobi leaves).
+
 Host spans (``jax.profiler.TraceAnnotation``) mark ``Experiment.run`` on
 the profiler's host clock: ``dfrc.prepare`` (canonicalise and transfer the
 inputs), ``dfrc.dispatch`` (the jitted program's call) and ``dfrc.fetch``
@@ -35,6 +40,8 @@ SOLVE = "dfrc.solve"
 EIGH = "dfrc.eigh"
 EVAL = "dfrc.eval"
 DEVICE_SCOPES = (INPUT, COLLECT, SOLVE, EIGH, EVAL)
+EIGH_SPLIT = "eigh_split"
+EIGH_LEAF = "eigh_leaf"
 
 PREPARE = "dfrc.prepare"
 DISPATCH = "dfrc.dispatch"

@@ -6,7 +6,8 @@ VMEM than the kernel may use, compiles there and is refused on the chip.
 These tests compile each kernel ahead of time for a v5e topology that is
 described, not attached, at the widths the chip runs (NARMA10's N = 900,
 the batches ``auto_block_s`` tiles, a Gram at F = 901 padded to 1024), and
-assert that the kernel reached Mosaic as a ``tpu_custom_call``.
+assert that the kernel reached Mosaic as a ``tpu_custom_call``.  The GCV
+solve's QDWH ``eigh`` is compiled under a 4-device mesh the same way.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and a file that loaded it
@@ -134,3 +135,29 @@ def test_dfr_scan_sharded_over_four_chips(topo):
     # each device holds its quarter of the [B, K, N] states
     states_bytes = batch * 64 * n_nodes * 4
     assert compiled.memory_analysis().output_size_in_bytes < states_bytes / 2
+
+
+def test_gcv_solve_sharded_over_four_chips(topo):
+    """Under a 4-device ("data",) mesh the GCV solve's QDWH ``eigh`` (F >
+    256) maps each device's own shard of the batch: the outputs stay
+    sharded and no collective moves a Gram between devices.  F = 300 takes
+    the path F = 901 takes, at a fraction of its compile time."""
+    from repro.compat import use_mesh
+    from repro.pipeline import ridge, scopes
+
+    mesh = jax.sharding.Mesh(np.asarray(topo.devices).reshape(4), ("data",))
+    rows = NamedSharding(mesh, P("data"))
+    f, batch = 300, 8
+    solve = jax.vmap(lambda g, c, y2: ridge.solve_gcv(g, c, y2, 1000, (1e-10, 1e-6, 1e-2)))
+    with use_mesh(mesh):
+        compiled = jax.jit(solve).lower(
+            jax.ShapeDtypeStruct((batch, f, f), jnp.float32, sharding=rows),
+            jax.ShapeDtypeStruct((batch, f, 1), jnp.float32, sharding=rows),
+            jax.ShapeDtypeStruct((batch,), jnp.float32, sharding=rows),
+        ).compile()
+    for out in compiled.output_shardings:
+        assert out.spec == P("data"), out
+    text = compiled.as_text()
+    assert scopes.EIGH_SPLIT in text
+    for collective in ("all-gather", "all-to-all", "collective-permute", "all-reduce"):
+        assert collective not in text, collective
