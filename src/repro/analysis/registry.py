@@ -204,9 +204,9 @@ def _device_sweep_program(name, *, state_dtype="float32", use_kernel=False):
     return Program(fn, args + lanes, name=name)
 
 
-# The swept map runs on the jnp fast path (the kernel keeps static models),
-# so the scan budget is its true nesting: fit and eval each run the chunk
-# scan -> per-chunk period scan -> in-period node chain scan.
+# These swept maps run on the jnp fast path, so the scan budget is its true
+# nesting: fit and eval each run the chunk scan -> per-chunk period scan ->
+# in-period node chain scan.
 _SWEEP_SCANS = 6
 
 

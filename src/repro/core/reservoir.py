@@ -117,18 +117,24 @@ def _states_fast_p(model, p, u: jnp.ndarray, s0: jnp.ndarray) -> jnp.ndarray:
     return jnp.moveaxis(states, 0, 1)
 
 
-def _kernel_states(model, j, mask, s0, *, block_s, return_final, state_dtype):
+def _kernel_states(model, j, mask, s0, *, block_s, return_final, state_dtype,
+                   dev_params=None):
     """The Pallas ``dfr_scan`` over [B, K] inputs, one launch per device
-    shard of B under an active mesh (parallel/sharding.over_batch_shards)."""
+    shard of B under an active mesh (parallel/sharding.over_batch_shards).
+    ``dev_params`` leaves become [B] lanes, sharded with the batch."""
     from repro.kernels.dfr_scan import ops as dfr_ops
     from repro.parallel.sharding import over_batch_shards
 
-    def scan(jb, m, s):
+    def scan(jb, m, s, p=None):
         return dfr_ops.dfr_scan(model, jb, m, s, block_s=block_s,
                                 return_final=return_final,
-                                out_dtype=state_dtype)
+                                out_dtype=state_dtype, params=p)
 
-    return over_batch_shards(scan, (j, mask, s0), (True, mask.ndim == 2, True))
+    args, batched = (j, mask, s0), (True, mask.ndim == 2, True)
+    if dev_params is not None:
+        args += (dfr_ops.lane_params(dev_params, j.shape[0]),)
+        batched += (True,)
+    return over_batch_shards(scan, args, batched)
 
 
 def generate_states(
@@ -154,8 +160,8 @@ def generate_states(
     ``devices.cmt.CMTSweepParams``; leaves scalar or [B], one grid point per
     batch lane) into the model's ``node_update_p``/``period_update_p``
     contract — how ``devices/sweep.py`` runs a whole (detuning × loss ×
-    power) map as one program.  jnp paths only; the Pallas kernel keeps the
-    static-model contract (per-lane parameter tiles are a ROADMAP follow-on).
+    power) map as one program.  On the kernel path the leaves become per-lane
+    [S, L] parameter tiles of ``dfr_scan`` (DESIGN.md §14).
 
     ``return_final=True`` additionally returns the final reservoir state
     [..., N] — feed it back as ``s0`` to resume the scan (train -> test
@@ -179,13 +185,9 @@ def generate_states(
             s0b = jnp.broadcast_to(s0b[None], (jb.shape[0], n_nodes))
 
     if method == "kernel":
-        if dev_params is not None:
-            raise NotImplementedError(
-                "dev_params (traced per-lane device parameters) are not "
-                "supported on the Pallas kernel path; sweep with "
-                "method='fast' or 'ref' (ROADMAP: swept-params kernel tiles)")
         out = _kernel_states(model, jb, mask, s0b, block_s=block_s,
-                             return_final=return_final, state_dtype=state_dtype)
+                             return_final=return_final, state_dtype=state_dtype,
+                             dev_params=dev_params)
         states, s_final = out if return_final else (out, None)
     else:
         u = masked_input(jb, mask)

@@ -133,7 +133,8 @@ def run_device_sweep(model: MRCavityCMT, grid: SweepGrid, dataset, *,
 
     Swept parameters ride the batch lanes as operands, so calling this again
     with a same-shape grid of different VALUES reuses the compiled program
-    (``pipeline_cache_size()`` proves it).
+    (``pipeline_cache_size()`` proves it).  ``state_method="kernel"`` runs
+    the map on the Pallas ``dfr_scan``, the parameters as per-lane tiles.
     """
     # lazy import: repro.pipeline imports repro.core, which must finish
     # initialising before the devices package pulls the pipeline in

@@ -17,6 +17,7 @@ s(t−τ) across periods of the same batch tile.
   mask    [N, 1]                 block [N, 1]       (whole, every step)
        or [N, B_s, B_l]          block [N, S, L]    @ (0, b·S, 0)  (per-lane)
   s0      [N, B_s, B_l]          block [N, S, L]    @ (0, b·S, 0)
+  params  P × [B_s, B_l]         block [S, L]       @ (b·S, 0)     (optional)
   out     [K, N, B_s, B_l]       block [1, N, S, L] @ (k, 0, b·S, 0)
   fin     [N, B_s, B_l]          block [N, S, L]    @ (0, b·S, 0)
   scratch s_prev [N, S, L] f32, s_last [S, L] f32
@@ -30,6 +31,13 @@ f32 scratch values the uninterrupted scan would have kept in VMEM (DESIGN.md
 (the paper's single-accelerator sweep — every lane shares the MLS mask) or a
 per-lane [N, S, L] tile (WDM ensembles: each batch lane is a wavelength
 channel with its own mask; pipeline/experiment.channel_states).
+
+``params`` (optional) are per-lane device parameters (DESIGN.md §14): a
+pytree of [S, L] tiles, one grid point of a device design-space sweep per
+batch lane, handed to ``model.node_update_p``.  Their block index moves with
+the batch tile only, so each tile is fetched once and stays in VMEM for all
+K periods; they are operands, so new values compile nothing.  Without them
+the kernel, its operands and its body are the static-model ones.
 
 The node chain (θ coupling) is sequential by construction — the realised
 branch bit of node i−1 feeds the value of node i (nonlinear.py docstring) —
@@ -59,8 +67,11 @@ from repro.kernels import VMEM_LIMIT_BYTES
 LANES = 128
 
 
-def _kernel(model, n_nodes, per_lane,
-            j_ref, mask_ref, s0_ref, out_ref, fin_ref, s_prev_ref, s_last_ref):
+def _kernel(model, n_nodes, per_lane, params_tree,
+            j_ref, mask_ref, s0_ref, *refs):
+    n_params = 0 if params_tree is None else params_tree.num_leaves
+    param_refs = refs[:n_params]
+    out_ref, fin_ref, s_prev_ref, s_last_ref = refs[n_params:]
     k = pl.program_id(1)
     n_k = pl.num_programs(1)
 
@@ -71,6 +82,13 @@ def _kernel(model, n_nodes, per_lane,
         s_last_ref[...] = s0_ref[n_nodes - 1, :, :].astype(jnp.float32)
 
     j_k = j_ref[0, :, :].astype(jnp.float32)  # [S, L] — this period's sample
+    if params_tree is None:
+        update = model.node_update
+    else:
+        p = jax.tree.unflatten(params_tree, [r[...] for r in param_refs])
+
+        def update(u_i, s_tau_i, s_left):
+            return model.node_update_p(p, u_i, s_tau_i, s_left)
 
     def node(i, s_last):
         if per_lane:
@@ -79,7 +97,7 @@ def _kernel(model, n_nodes, per_lane,
             m_i = mask_ref[i, 0]                            # lane-broadcast
         u_i = j_k * m_i                                 # input layer: u = j·m
         s_tau_i = s_prev_ref[i, :, :]                   # s(t−τ): same node, prev period
-        s_i = model.node_update(u_i, s_tau_i, s_last)   # NL node (θ-chain via s_last)
+        s_i = update(u_i, s_tau_i, s_last)              # NL node (θ-chain via s_last)
         s_prev_ref[i, :, :] = s_i                       # becomes s(t−τ) for period k+1
         out_ref[0, i, :, :] = s_i.astype(out_ref.dtype)
         return s_i
@@ -105,6 +123,7 @@ def dfr_scan_tiled(
     block_s: int = 8,
     interpret: bool = False,
     out_dtype=None,      # state-tensor dtype (default: j.dtype); fin stays j.dtype
+    params=None,         # pytree of [S_total, L] per-lane device parameters
 ) -> tuple[jnp.ndarray, jnp.ndarray]:  # ([K, N, S_total, L], [N, S_total, L])
     out_dtype = jnp.dtype(out_dtype) if out_dtype is not None else j.dtype
     k_periods, s_total, lanes = j.shape
@@ -132,7 +151,9 @@ def dfr_scan_tiled(
     else:
         mask_spec = pl.BlockSpec((n_nodes, 1), lambda b, k: (0, 0))
 
-    kernel = functools.partial(_kernel, model, n_nodes, per_lane)
+    leaves, params_tree = (jax.tree.flatten(params) if params is not None
+                           else ([], None))
+    kernel = functools.partial(_kernel, model, n_nodes, per_lane, params_tree)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -140,7 +161,7 @@ def dfr_scan_tiled(
             pl.BlockSpec((1, block_s, lanes), lambda b, k: (k, b, 0)),
             mask_spec,
             pl.BlockSpec((n_nodes, block_s, lanes), lambda b, k: (0, b, 0)),
-        ],
+        ] + [pl.BlockSpec((block_s, lanes), lambda b, k: (b, 0))] * len(leaves),
         out_specs=[
             pl.BlockSpec((1, n_nodes, block_s, lanes), lambda b, k: (k, 0, b, 0)),
             pl.BlockSpec((n_nodes, block_s, lanes), lambda b, k: (0, b, 0)),
@@ -156,4 +177,4 @@ def dfr_scan_tiled(
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="dfr_scan",
-    )(j, mask, s0)
+    )(j, mask, s0, *leaves)

@@ -17,9 +17,19 @@ wavelength channel).  ``return_final=True`` additionally returns the final
 reservoir state [B, N] straight from the kernel's VMEM carry: feeding it
 back as ``s0`` of a following call resumes the scan bit-exactly for f32 I/O
 (chunked streaming, train -> test continuation; DESIGN.md §8).
+
+``params`` are per-lane device parameters (a pytree such as
+``devices.cmt.CMTSweepParams`` with scalar or [B] leaves, DESIGN.md §14):
+each leaf goes to the kernel as an [S, L] lane tile, the padded lanes
+holding the model's own ``sweep_point()`` so that they stay finite.
+
+Each dispatch is counted once per trace (``dispatch_counts``): lanes, lane
+tiles, per-lane parameter leaves and the model's ``n_substeps``.
 """
 
 from __future__ import annotations
+
+import collections
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +37,19 @@ import jax.numpy as jnp
 from .dfr_scan import LANES, dfr_scan_tiled
 
 _BLOCK_S_CHOICES = (1, 2, 4, 8, 16, 32)
+_DISPATCHES = collections.Counter()
+
+
+def lane_params(params, b: int):
+    """``params`` leaves (scalars or [b]) as the kernel's float32 [b] lanes."""
+    return jax.tree.map(
+        lambda leaf: jnp.broadcast_to(jnp.asarray(leaf, jnp.float32), (b,)), params)
+
+
+def dispatch_counts() -> list[dict]:
+    """The kernel's dispatches traced in this process, one dict per distinct
+    layout with the number of traces (``traces``) that made it."""
+    return [dict(key, traces=n) for key, n in _DISPATCHES.items()]
 
 
 def _auto_interpret() -> bool:
@@ -78,6 +101,7 @@ def dfr_scan(
     interpret: bool | None = None,
     return_final: bool = False,
     out_dtype=None,
+    params=None,
 ):
     """States [B, K, N]; with ``return_final`` also the final state [B, N].
 
@@ -111,9 +135,21 @@ def dfr_scan(
         maskt = jnp.pad(mask, ((0, b_pad), (0, 0))).T.reshape(n_nodes, s_total, LANES)
     else:
         maskt = mask.reshape(n_nodes, 1)
+    paramst = None
+    if params is not None:
+        # padded lanes run the model's own operating point, so stay finite
+        paramst = jax.tree.map(
+            lambda leaf, pad: jnp.pad(leaf, (0, b_pad), constant_values=pad)
+            .reshape(s_total, LANES), lane_params(params, b), model.sweep_point())
+    _DISPATCHES[(("lanes", b), ("padded_lanes", b + b_pad),
+                 ("lane_tiles", s_total // block_s), ("block_s", block_s),
+                 ("periods", k_periods), ("nodes", n_nodes),
+                 ("param_leaves", len(jax.tree.leaves(params))),
+                 ("n_substeps", getattr(model, "n_substeps", 1)))] += 1
 
     out, fin = dfr_scan_tiled(model, jt, maskt, s0t, block_s=block_s,
-                              interpret=interpret, out_dtype=out_dtype)
+                              interpret=interpret, out_dtype=out_dtype,
+                              params=paramst)
     # [K, N, S, L] -> [B, K, N];  [N, S, L] -> [B, N]
     out = out.reshape(k_periods, n_nodes, s_total * LANES)
     states = jnp.moveaxis(out, -1, 0)[:b]

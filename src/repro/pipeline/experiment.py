@@ -373,6 +373,34 @@ def _streaming_metrics(acc, t_test: int, *, channel_axis: bool):
     return nrmse, ser
 
 
+def _input_layer(cfg: ExperimentConfig, tr_in, te_in):
+    """The input layer: per-instance normalisation (fitted on the train
+    segment), sample-and-hold and gain -> the drives (j_tr, j_te) [B, T*]."""
+    with jax.named_scope(scopes.INPUT):
+        if cfg.normalize_input:
+            lo = jnp.min(tr_in, axis=1, keepdims=True)
+            scale = 1.0 / (jnp.max(tr_in, axis=1, keepdims=True) - lo + 1e-12)
+        else:
+            lo, scale = 0.0, 1.0
+        j_tr = sample_and_hold((tr_in - lo) * scale * cfg.input_gain)
+        j_te = sample_and_hold((te_in - lo) * scale * cfg.input_gain)
+        return maybe_shard(j_tr, ("pod", "data")), maybe_shard(j_te, ("pod", "data"))
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def _pipeline_states(cfg: ExperimentConfig, mask, tr_in, te_in, dev_params=None):
+    """The states ``_run_pipeline`` fits and evaluates its readouts on:
+    train from the dark state, test resumed from the train segment's end,
+    in the streamed chunks' dtype."""
+    j_tr, j_te = _input_layer(cfg, tr_in, te_in)
+    dtype = cfg._stream_state_dtype_arg if cfg.stream_chunk_k is not None else None
+    st_tr, s_end = _gen_states(cfg, mask, j_tr, wdm=False, return_final=True,
+                               state_dtype=dtype, dev_params=dev_params)
+    st_te = _gen_states(cfg, mask, j_te, wdm=False, s0=s_end,
+                        state_dtype=dtype, dev_params=dev_params)
+    return st_tr, st_te
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "wdm", "shared"))
 def _run_pipeline(cfg: ExperimentConfig, mask, tr_in, tr_tg, te_in, te_tg,
                   wdm: bool = False, shared: bool = False, dev_params=None):
@@ -400,17 +428,7 @@ def _run_pipeline(cfg: ExperimentConfig, mask, tr_in, tr_tg, te_in, te_tg,
     composed stage-chain fit/eval (``mask`` then the per-stage mask-stack
     tuple); both are streaming-only (enforced at config construction).
     """
-    # -- input layer: per-instance normalisation + sample-and-hold + gain ----
-    with jax.named_scope(scopes.INPUT):
-        if cfg.normalize_input:
-            lo = jnp.min(tr_in, axis=1, keepdims=True)
-            scale = 1.0 / (jnp.max(tr_in, axis=1, keepdims=True) - lo + 1e-12)
-        else:
-            lo, scale = 0.0, 1.0
-        j_tr = sample_and_hold((tr_in - lo) * scale * cfg.input_gain)
-        j_te = sample_and_hold((te_in - lo) * scale * cfg.input_gain)
-        j_tr = maybe_shard(j_tr, ("pod", "data"))
-        j_te = maybe_shard(j_te, ("pod", "data"))
+    j_tr, j_te = _input_layer(cfg, tr_in, te_in)
 
     if cfg.stream_chunk_k is not None:
         # -- streaming fused path (DESIGN.md §8/§9/§13): reservoir chunks
@@ -533,8 +551,26 @@ def _materialized_eval(cfg: ExperimentConfig, st_te, te_tg, w_fit, lam_idx):
     return y_out, nrmse, ser, lam, w_fit
 
 
+@jax.jit
+def _end_to_end(outs):
+    """Outputs of one dtype end to end in one flat array."""
+    return jnp.concatenate([jnp.ravel(x) for x in outs])
+
+
 def _pack_result(y, nrmse, ser, lam, w) -> ExperimentResult:
-    """Device outputs -> host ExperimentResult (shared by both experiments)."""
+    """Device outputs -> host ExperimentResult (shared by both experiments),
+    fetched in one device-to-host transfer: outputs of one dtype are laid
+    end to end on the device first, so the host waits once a call."""
+    outs = [x for x in (y, nrmse, ser, lam, w) if x is not None]
+    if len({x.dtype for x in outs}) == 1:
+        flat = np.asarray(_end_to_end(outs))
+        cuts = np.cumsum([x.size for x in outs])[:-1]
+        host = [a.reshape(x.shape) for a, x in zip(np.split(flat, cuts), outs)]
+    else:
+        host = jax.device_get(outs)
+    if y is not None:
+        y, *host = host
+    nrmse, ser, lam, w = host
     # w is [B, N + 1, C]; drop the channel axis only when there is a
     # single output channel (C > 1 used to be silently truncated here).
     w = np.asarray(w)
@@ -544,6 +580,17 @@ def _pack_result(y, nrmse, ser, lam, w) -> ExperimentResult:
         y_pred=None if y is None else np.asarray(y),
         nrmse=np.asarray(nrmse), ser=np.asarray(ser),
         lam=np.asarray(lam), readout_w=w)
+
+
+def _check_lanes(dev_params, b: int) -> None:
+    """``dev_params`` leaves must be scalars or [B], one value per lane
+    (the kernel wrapper makes them float32 lanes, ``dfr_scan.ops``)."""
+    for leaf in jax.tree.leaves(dev_params):
+        shape = jnp.shape(leaf)
+        if len(shape) > 1 or (len(shape) == 1 and shape[0] != b):
+            raise ValueError(
+                f"dev_params leaves must be scalars or [{b}] "
+                f"(one value per batch lane), got shape {shape}")
 
 
 class Experiment:
@@ -583,7 +630,8 @@ class Experiment:
 
         Under a profiler trace the call shows as three host spans
         (``scopes.HOST_SPANS``): ``dfrc.prepare``, ``dfrc.dispatch`` and
-        ``dfrc.fetch``, the last holding the wait for the device.
+        ``dfrc.fetch``, the last holding the wait for the device; inside
+        ``dfrc.prepare``, ``dfrc.sweep_params`` checks the parameter lanes.
         """
         with jax.profiler.TraceAnnotation(scopes.PREPARE):
             operands = self._operands(inputs_train, targets_train,
@@ -606,6 +654,19 @@ class Experiment:
         return _run_pipeline.lower(self.config, self.mask, *operands,
                                    dev_params=dev_params)
 
+    def states(self, inputs_train, inputs_test, *, dev_params=None):
+        """The reservoir states the readouts of ``run`` are fitted and
+        evaluated on, for the same inputs and ``dev_params``: (train
+        [B, T_train, N], washout included; test [B, T_test, N]), through
+        the same input layer and state method (DESIGN.md §14)."""
+        if self.config.topology is not None:
+            raise ValueError("states() reads the single-loop workload")
+        tr_in = _canon_batch(inputs_train, "inputs_train")
+        te_in = _canon_batch(inputs_test, "inputs_test")
+        if dev_params is not None:
+            _check_lanes(dev_params, tr_in.shape[0])
+        return _pipeline_states(self.config, self.mask, tr_in, te_in, dev_params)
+
     def _operands(self, inputs_train, targets_train, inputs_test, targets_test,
                   dev_params):
         """Canonicalised device operands (tr_in, tr_tg, te_in, te_tg)."""
@@ -623,18 +684,8 @@ class Experiment:
                 raise ValueError(
                     "dev_params with a composed topology is not supported; "
                     "sweep the single-loop workload")
-            if self.config.state_method == "kernel":
-                raise ValueError(
-                    "dev_params rides the jnp state paths; set "
-                    "state_method='fast' or 'ref' (ROADMAP: swept-params "
-                    "kernel tiles)")
-            b = tr_in.shape[0]
-            for leaf in jax.tree.leaves(dev_params):
-                arr = jnp.asarray(leaf)
-                if arr.ndim > 1 or (arr.ndim == 1 and arr.shape[0] != b):
-                    raise ValueError(
-                        f"dev_params leaves must be scalars or [{b}] "
-                        f"(one value per batch lane), got shape {arr.shape}")
+            with jax.profiler.TraceAnnotation(scopes.SWEEP_PARAMS):
+                _check_lanes(dev_params, tr_in.shape[0])
         return tr_in, tr_tg, te_in, te_tg
 
     def run_dataset(self, ds) -> ExperimentResult:

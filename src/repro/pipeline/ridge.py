@@ -37,6 +37,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.graph import ReservoirGraph, stage_link_drive, stage_states
 from repro.core.reservoir import generate_channel_states, generate_states
@@ -73,8 +74,8 @@ def gram(x: jnp.ndarray, y: jnp.ndarray, *, use_kernel: bool = False):
 
 
 # On the TPU an eigh of at most this many rows is XLA's Jacobi, natively
-# batched; a wider one is JAX's QDWH divide and conquer, which ``qdwh_eigh``
-# runs on fewer zeros
+# batched, refined once (``_jacobi_refined``); a wider one is JAX's QDWH
+# divide and conquer, which ``qdwh_eigh`` runs on fewer zeros
 _JACOBI_ROWS = 256
 
 
@@ -82,14 +83,104 @@ def _lapack_eigh(g):
     return tuple(jnp.linalg.eigh(g))
 
 
+def _round_robin(n: int) -> np.ndarray:
+    """[n - 1, n] index orders for a cyclic Jacobi sweep over n (even)
+    indices: round r pairs (order[2k], order[2k + 1]), n / 2 disjoint pairs,
+    and the n - 1 rounds meet every pair once (the circle method)."""
+    rounds = []
+    for r in range(n - 1):
+        ring = [0] + [1 + (i + r) % (n - 1) for i in range(n - 1)]
+        rounds.append([ring[i] for k in range(n // 2) for i in (k, n - 1 - k)])
+    return np.asarray(rounds, np.int32)
+
+
+def _rotate_pairs(x, c, s, axis):
+    """Rotate the adjacent pairs (2k, 2k + 1) of ``x`` along ``axis`` (-1
+    or -2) by the Jacobi rotations (c, s): the columns of X J, or the rows
+    of J^T X."""
+    a, b = (x[..., 0::2], x[..., 1::2]) if axis == -1 else (x[..., 0::2, :], x[..., 1::2, :])
+    pair = jnp.stack([c * a - s * b, s * a + c * b], axis=axis)
+    return pair.reshape(x.shape)
+
+
+def _jacobi_sweep(h, q):
+    """One cyclic Jacobi sweep in float32 on the symmetric ``h`` [..., n, n]
+    (n even), its rotations applied to the columns of ``q`` [..., m, n]:
+    each round rotates n / 2 disjoint pairs (Golub & Van Loan, Alg. 8.5.1).
+    On a nearly diagonal ``h`` the off-diagonal falls quadratically."""
+
+    # a pair whose coupling is at rounding of the largest diagonal entry is
+    # left alone: rotating it (a cluster of equal eigenvalues, say) moves
+    # nothing above rounding and costs an ulp of orthogonality
+    floor = jnp.finfo(h.dtype).eps * jnp.max(
+        jnp.abs(jnp.diagonal(h, axis1=-2, axis2=-1)), axis=-1)[..., None]
+
+    # unrolled over the rounds (F is static and at most _JACOBI_ROWS): h and
+    # q are kept in the current round's index order, moved to the next
+    # round's by one gather per axis, and put back in order at the end
+    where = np.arange(h.shape[-1])
+    for order in _round_robin(h.shape[-1]):
+        move = np.argsort(where)[order]          # positions of ``order`` now
+        h = jnp.take(jnp.take(h, move, axis=-2), move, axis=-1)
+        q = jnp.take(q, move, axis=-1)
+        where = order
+        d = jnp.diagonal(h, axis1=-2, axis2=-1)
+        hpp, hqq = d[..., 0::2], d[..., 1::2]
+        hpq = jnp.diagonal(h[..., 0::2, 1::2], axis1=-2, axis2=-1)
+        zero = jnp.abs(hpq) <= floor
+        tau = (hqq - hpp) / (2 * jnp.where(zero, 1.0, hpq))
+        t = jnp.where(tau >= 0, 1.0, -1.0) / (jnp.abs(tau) + jnp.sqrt(1 + tau * tau))
+        t = jnp.where(zero, 0.0, t)
+        c = 1 / jnp.sqrt(1 + t * t)
+        s = t * c
+        h = _rotate_pairs(h, c[..., :, None], s[..., :, None], -2)
+        h = _rotate_pairs(h, c[..., None, :], s[..., None, :], -1)
+        q = _rotate_pairs(q, c[..., None, :], s[..., None, :], -1)
+    back = np.argsort(where)
+    return jnp.take(jnp.take(h, back, axis=-2), back, axis=-1), jnp.take(q, back, axis=-1)
+
+
+def _rayleigh_ritz(g, q):
+    """One Rayleigh-Ritz step on an approximate eigenbasis ``q`` of ``g``:
+    the Gram in that basis, H = Q^T G Q (nearly diagonal), diagonalised by
+    one Jacobi sweep whose rotations turn Q; (eigenvalues ascending,
+    eigenvectors).  Batched like ``jnp.linalg.eigh``.  From a basis ~50 eps
+    off (as the TPU's Jacobi leaves the chan-eq Grams) one sweep reaches
+    LAPACK's residual; a second changes nothing.
+
+    XLA's Jacobi cannot do this step: it takes H as already converged and
+    returns it untouched.  An odd F is padded by one decoupled index."""
+    f = g.shape[-1]
+    pad = f % 2
+    with jax.named_scope(scopes.EIGH_REFINE):
+        h = jnp.matmul(jnp.swapaxes(q, -1, -2),
+                       jnp.matmul(g, q, precision=_F32), precision=_F32)
+        widen = [(0, 0)] * (h.ndim - 2)
+        h = jnp.pad(h, widen + [(0, pad), (0, pad)])
+        h, q = _jacobi_sweep(h, jnp.pad(q, widen + [(0, 0), (0, pad)]))
+        evals = jnp.diagonal(h, axis1=-2, axis2=-1)[..., :f]
+        order = jnp.argsort(evals, axis=-1)
+        return (jnp.take_along_axis(evals, order, axis=-1),
+                jnp.take_along_axis(q[..., :f], order[..., None, :], axis=-1))
+
+
+def _jacobi_refined(g):
+    """XLA's Jacobi ``eigh`` of a Gram, then one Rayleigh-Ritz step.
+
+    The TPU's Jacobi stops at a tolerance of 1e-6, which leaves residuals
+    ||G Q - Q L|| of several 1e-6 of the largest eigenvalue, where LAPACK
+    leaves a few eps; ``solve_gcv``'s 4 eps cut-off assumes the latter."""
+    return _rayleigh_ritz(g, jnp.linalg.eigh(g)[1])
+
+
 @jax.custom_batching.custom_vmap
 def _eigh(g):
     """``jnp.linalg.eigh`` of one Gram [F, F]: (eigenvalues ascending,
-    eigenvectors); wider than ``_JACOBI_ROWS`` on the TPU ``qdwh_eigh``'s,
-    one matrix at a time under ``vmap``."""
-    if g.shape[-1] <= _JACOBI_ROWS:
-        return _lapack_eigh(g)
-    return jax.lax.platform_dependent(g, tpu=qdwh_eigh.eigh, default=_lapack_eigh)
+    eigenvectors).  On the TPU, up to ``_JACOBI_ROWS`` XLA's Jacobi refined
+    (``_jacobi_refined``), wider ``qdwh_eigh``'s, one matrix at a time
+    under ``vmap``; elsewhere LAPACK."""
+    tpu = _jacobi_refined if g.shape[-1] <= _JACOBI_ROWS else qdwh_eigh.eigh
+    return jax.lax.platform_dependent(g, tpu=tpu, default=_lapack_eigh)
 
 
 @_eigh.def_vmap
@@ -97,7 +188,8 @@ def _eigh_vmap(axis_size, in_batched, g):
     if not in_batched[0]:
         return _eigh(g), (False, False)
     if g.shape[-1] <= _JACOBI_ROWS:
-        return jax.vmap(_lapack_eigh)(g), (True, True)
+        return jax.lax.platform_dependent(
+            g, tpu=_jacobi_refined, default=jax.vmap(_lapack_eigh)), (True, True)
     # a map of ``_eigh``, so a vmap around this one maps again; under a mesh
     # each device maps its own shard of the batch
     return jax.lax.platform_dependent(
@@ -119,7 +211,8 @@ def solve_gcv(
     the static ``lambdas`` tuple.  A single-element tuple skips nothing but
     costs one extra reduction; the eigendecomposition dominates either way.
     On the TPU with F > 256 the eigendecomposition is ``qdwh_eigh``'s divide
-    and conquer, one matrix at a time under ``vmap`` (``_eigh``); else
+    and conquer, one matrix at a time under ``vmap``, and at F <= 256 XLA's
+    Jacobi refined by one Rayleigh-Ritz step (``_eigh``); else
     ``jnp.linalg.eigh``.
     """
     f = g.shape[0]
@@ -638,8 +731,8 @@ def fit_ridge_streaming(
     ``dev_params`` (a traced device operating-point pytree, e.g.
     ``devices.cmt.CMTSweepParams`` with [B] leaves) threads per-lane swept
     device parameters into state generation — an *operand*, so a design-
-    space sweep over it reuses this compiled program (DESIGN.md §14).
-    jnp state methods only (``generate_states`` rejects kernel+params).
+    space sweep over it reuses this compiled program (DESIGN.md §14).  On
+    the kernel path they are ``dfr_scan``'s per-lane parameter tiles.
     """
     j, y = _canon_stream(j, targets)
 

@@ -19,13 +19,15 @@ solve called inside state collection counts as the solve, and the
 Inside ``dfrc.eigh`` the divide and conquer (``qdwh_eigh``) names its two
 kinds of step, without the ``dfrc.`` prefix so that ``dfrc.eigh`` stays
 their innermost phase and its time counts them both: ``eigh_split`` (the
-QDWH splits) and ``eigh_leaf`` (the Jacobi leaves).
+QDWH splits) and ``eigh_leaf`` (the Jacobi leaves).  At F <= 256 on the
+TPU the Rayleigh-Ritz refinement of XLA's Jacobi is ``eigh_refine``.
 
 Host spans (``jax.profiler.TraceAnnotation``) mark ``Experiment.run`` on
 the profiler's host clock: ``dfrc.prepare`` (canonicalise and transfer the
 inputs), ``dfrc.dispatch`` (the jitted program's call) and ``dfrc.fetch``
-(the device-to-host transfer of the results).  Without an active trace
-each span is one no-op context.
+(the device-to-host transfer of the results).  Inside ``dfrc.prepare``,
+``dfrc.sweep_params`` checks a device sweep's per-lane parameters.  Without
+an active trace each span is one no-op context.
 """
 
 from __future__ import annotations
@@ -42,11 +44,13 @@ EVAL = "dfrc.eval"
 DEVICE_SCOPES = (INPUT, COLLECT, SOLVE, EIGH, EVAL)
 EIGH_SPLIT = "eigh_split"
 EIGH_LEAF = "eigh_leaf"
+EIGH_REFINE = "eigh_refine"
 
 PREPARE = "dfrc.prepare"
 DISPATCH = "dfrc.dispatch"
 FETCH = "dfrc.fetch"
 HOST_SPANS = (PREPARE, DISPATCH, FETCH)
+SWEEP_PARAMS = "dfrc.sweep_params"
 
 
 def scoped(name: str):

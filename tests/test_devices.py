@@ -124,17 +124,21 @@ def test_kernel_matches_ref():
     assert float(jnp.max(jnp.abs(a - b))) < 1e-5
 
 
+@pytest.mark.parametrize("swept", [False, True], ids=["point", "swept"])
 @pytest.mark.parametrize("method", ["ref", "fast", "kernel"])
-def test_chunk_resume_bit_exact(method):
+def test_chunk_resume_bit_exact(method, swept):
     """Resuming from the carried final state replays the uninterrupted scan
     exactly — the CMT adiabatic closure is a function of the carried state
-    alone, so chunk boundaries are invisible."""
+    alone, so chunk boundaries are invisible; with per-lane device
+    parameters too (on the kernel path, its parameter tiles)."""
     j = _stream(3)
-    full = generate_states(CMT_HOT, j, MASK, method=method)
+    p = _lane_grid() if swept else None
+    full = generate_states(CMT_HOT, j, MASK, method=method, dev_params=p)
     s0, out = None, []
     for lo, hi in ((0, 13), (13, 14), (14, K)):
         states, s0 = generate_states(CMT_HOT, j[:, lo:hi], MASK, s0=s0,
-                                     method=method, return_final=True)
+                                     method=method, return_final=True,
+                                     dev_params=p)
         out.append(np.asarray(states))
     assert np.array_equal(np.concatenate(out, axis=1), np.asarray(full))
 
@@ -150,7 +154,7 @@ def _lane_grid():
                           power=jnp.asarray([0.0, 0.5, 1.0], jnp.float32))
 
 
-@pytest.mark.parametrize("method", ["ref", "fast"])
+@pytest.mark.parametrize("method", ["ref", "fast", "kernel"])
 def test_swept_lanes_match_unswept_points(method):
     """Each batch lane of a dev_params run equals the dedicated model built
     at that grid point (κ pinned to the base model's calibration anchor —
@@ -190,10 +194,93 @@ def test_dev_params_scalar_leaves_broadcast():
     assert float(jnp.max(jnp.abs(a - b))) < 1e-5
 
 
+def _random_lanes(seed: int, b: int) -> CMTSweepParams:
+    """A seeded random grid over the benchmark's box (loss >= 1)."""
+    rng = np.random.default_rng(seed)
+    return CMTSweepParams(
+        detune=jnp.asarray(rng.uniform(-2.0, 2.0, b), jnp.float32),
+        loss_scale=jnp.asarray(rng.uniform(1.0, 4.0, b), jnp.float32),
+        power=jnp.asarray(rng.uniform(0.0, 2.0, b), jnp.float32))
+
+
+@pytest.mark.parametrize("n_substeps", [1, 4])
+@pytest.mark.parametrize("b", [5, 130], ids=["one_tile_padded", "two_sublanes_padded"])
+def test_kernel_params_match_ref(b, n_substeps):
+    """The Pallas scan with per-lane parameter tiles against the sequential
+    oracle at the same parameters; both batches pad lanes (to 128 and 256)."""
+    model = dataclasses.replace(CMT_HOT, n_substeps=n_substeps)
+    j = _stream(8, k=24, b=b)
+    p = _random_lanes(9 + b, b)
+    a = generate_states(model, j, MASK, method="ref", dev_params=p)
+    k = generate_states(model, j, MASK, method="kernel", dev_params=p)
+    assert bool(jnp.all(jnp.isfinite(k)))
+    assert float(jnp.max(jnp.abs(a - k))) < 1e-6
+
+
+def test_kernel_zero_power_lane_is_silicon_mr():
+    """A power = 0 lane at the calibration point (detune 0, loss 1) of the
+    swept kernel gives the Silicon MR kernel's states."""
+    j = _stream(10)
+    p = CMTSweepParams(detune=jnp.zeros((B,)), loss_scale=jnp.ones((B,)),
+                       power=jnp.zeros((B,)))
+    a = generate_states(MR, j, MASK, method="kernel")
+    k = generate_states(CMT_HOT, j, MASK, method="kernel", dev_params=p)
+    assert float(jnp.max(jnp.abs(a - k))) < 1e-5
+
+
+def test_kernel_sweep_matches_fast_experiment():
+    """A chan-eq map through Experiment.run on the kernel path gives the
+    fast path's NRMSE and SER at every grid point."""
+    ds = tasks.channel_equalization(900, seed=4)
+    g = 6
+    p = _random_lanes(11, g)
+    args = [np.broadcast_to(np.asarray(a, np.float32)[None], (g, len(a)))
+            for a in (ds.inputs_train, ds.targets_train, ds.inputs_test,
+                      ds.targets_test)]
+    kw = dict(model=CMT_HOT, n_nodes=N, washout=30, ridge_l2=(1e-8, 1e-6, 1e-4),
+              state_noise_rel=0.0, stream_chunk_k=64, quantize=True)
+    fast = Experiment(ExperimentConfig(state_method="fast", **kw)).run(*args, dev_params=p)
+    kern = Experiment(ExperimentConfig(state_method="kernel", readout_use_kernel=True,
+                                       **kw)).run(*args, dev_params=p)
+    assert np.all(np.isfinite(kern.nrmse))
+    np.testing.assert_allclose(kern.nrmse, fast.nrmse, atol=1e-4)
+    assert np.max(np.abs(kern.ser - fast.ser)) <= 1.0 / len(ds.targets_test)
+
+
+@pytest.mark.parametrize("state_method", ["fast", "kernel"])
+def test_experiment_states_are_the_fitted_states(state_method):
+    """Experiment.states gives the states run fits and evaluates on: the
+    readout run returns reproduces its reported NRMSE on them."""
+    ds = tasks.channel_equalization(700, seed=5)
+    g = 4
+    p = _random_lanes(13, g)
+    args = [np.broadcast_to(np.asarray(a, np.float32)[None], (g, len(a)))
+            for a in (ds.inputs_train, ds.targets_train, ds.inputs_test,
+                      ds.targets_test)]
+    exp = Experiment(ExperimentConfig(
+        model=CMT_HOT, n_nodes=N, washout=30, ridge_l2=(1e-8, 1e-6, 1e-4),
+        state_noise_rel=0.0, stream_chunk_k=64, state_method=state_method,
+        readout_use_kernel=True, collect_y_pred=False))
+    res = exp.run(*args, dev_params=p)
+    tr, te = exp.states(args[0], args[2], dev_params=p)
+    assert tr.shape == (g, len(ds.inputs_train), N)
+    assert te.shape == (g, len(ds.inputs_test), N)
+    x = np.concatenate([np.asarray(te, np.float64), np.ones(te.shape[:2] + (1,))], axis=2)
+    y = np.einsum("btf,bf->bt", x, res.readout_w.astype(np.float64))
+    target = args[3].astype(np.float64)
+    nrmse = np.sqrt(np.mean((y - target) ** 2, axis=1) / np.var(target, axis=1))
+    np.testing.assert_allclose(res.nrmse, nrmse, rtol=1e-5)
+
+
 def test_dev_params_rejected_on_kernel_path():
-    with pytest.raises(NotImplementedError, match="kernel"):
-        generate_states(CMT_HOT, _stream(7), MASK, method="kernel",
-                        dev_params=_lane_grid())
+    """The kernel path takes per-lane device parameters for one shared
+    mask; per-channel WDM masks with them stay rejected."""
+    from repro.pipeline.experiment import _gen_states
+
+    cfg = ExperimentConfig(model=CMT_HOT, n_nodes=N, state_method="kernel")
+    masks = jnp.stack([MASK] * B)
+    with pytest.raises(NotImplementedError, match="per-channel"):
+        _gen_states(cfg, masks, _stream(7), wdm=True, dev_params=_lane_grid())
 
 
 def test_experiment_dev_params_validation():
@@ -202,9 +289,6 @@ def test_experiment_dev_params_validation():
     args = (ds.inputs_train[None, :], ds.targets_train[None, :],
             ds.inputs_test[None, :], ds.targets_test[None, :])
     p0 = CMTSweepParams(detune=0.0, loss_scale=1.0, power=0.0)
-    with pytest.raises(ValueError, match="kernel"):
-        Experiment(ExperimentConfig(state_method="kernel", **base)).run(
-            *args, dev_params=p0)
     topo = chain(ReservoirStage(model=CMT_HOT, n_nodes=N, mask_seed=3))
     with pytest.raises(ValueError, match="topology"):
         Experiment(ExperimentConfig(topology=topo, stream_chunk_k=16,
@@ -247,20 +331,24 @@ def test_stable_region_summary():
     assert region["summary"]["stable_power"] == [0.0, 1.0]
 
 
-def test_run_device_sweep_one_program_no_retrace():
+@pytest.mark.parametrize("state_method", ["fast", "kernel"])
+def test_run_device_sweep_one_program_no_retrace(state_method):
     """The whole map from one compiled program: a second sweep with NEW grid
-    values (same shapes) must leave the pipeline's jit cache untouched."""
+    values (same shapes) must leave the pipeline's jit cache untouched, on
+    the kernel path (parameter tiles are operands) as on the jnp one."""
     ds = tasks.narma10(300, seed=0)
     grid = SweepGrid(detune=(-0.5, 0.5), loss_scale=(1.0,), power=(0.0, 1.0))
     res = run_device_sweep(TWIN, grid, ds, n_nodes=N, washout=20,
-                           stream_chunk_k=32, ridge_l2=(1e-6, 1e-4))
+                           stream_chunk_k=32, ridge_l2=(1e-6, 1e-4),
+                           state_method=state_method)
     assert res.nrmse.shape == grid.shape
     assert np.all(np.isfinite(res.nrmse))
     c0 = pipeline_cache_size()
     shifted = SweepGrid(detune=(-0.25, 0.75), loss_scale=(1.1,),
                         power=(0.25, 1.25))
     res2 = run_device_sweep(TWIN, shifted, ds, n_nodes=N, washout=20,
-                            stream_chunk_k=32, ridge_l2=(1e-6, 1e-4))
+                            stream_chunk_k=32, ridge_l2=(1e-6, 1e-4),
+                            state_method=state_method)
     assert pipeline_cache_size() == c0
     assert not np.array_equal(res.nrmse, res2.nrmse)
 

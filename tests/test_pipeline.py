@@ -313,3 +313,29 @@ def test_run_marks_its_host_spans(narma_small_batch, tmp_path):
                     for line in plane.lines for ev in line.events
                     if ev.name in scopes.HOST_SPANS)
     assert [name for _, name in events] == list(scopes.HOST_SPANS)
+
+
+@pytest.mark.parametrize("y_dtype", [None, "float32", "int32"])
+def test_pack_result_returns_the_outputs_unchanged(y_dtype):
+    """The host result holds the device outputs exactly, whether they come
+    back end to end in one array (one dtype) or one by one (mixed dtypes),
+    with or without predictions."""
+    import jax.numpy as jnp
+
+    from repro.pipeline.experiment import _pack_result
+
+    rng = np.random.default_rng(7)
+    b, f, t = 5, 4, 3
+    nrmse, ser, lam = (rng.standard_normal(b).astype(np.float32) for _ in range(3))
+    w = rng.standard_normal((b, f, 1)).astype(np.float32)
+    y = None if y_dtype is None else rng.integers(-3, 4, (b, t)).astype(y_dtype)
+    res = _pack_result(None if y is None else jnp.asarray(y), *map(jnp.asarray,
+                                                                   (nrmse, ser, lam, w)))
+    for got, want in ((res.nrmse, nrmse), (res.ser, ser), (res.lam, lam),
+                      (res.readout_w, w[..., 0])):
+        np.testing.assert_array_equal(got, want)
+    if y is None:
+        assert res.y_pred is None
+    else:
+        assert res.y_pred.dtype == y.dtype
+        np.testing.assert_array_equal(res.y_pred, y)

@@ -10,8 +10,9 @@ eigenvalues, and one large eigenvalue over a flat rest.
 
 The dispatch (``ridge._eigh``) is checked by lowering ``vmap(solve_gcv)``
 for the TPU from the CPU: at F = 901 the decomposition is ``qdwh_eigh``'s
-and not JAX's agenda; at F = 31 it is still the native Jacobi; on the CPU
-it is LAPACK's, bit for bit.
+and not JAX's agenda; at F = 31 it is the native Jacobi refined by one
+Rayleigh-Ritz step (``eigh_refine``); on the CPU it is LAPACK's, bit for
+bit.
 """
 
 import re
@@ -167,6 +168,7 @@ def test_lowering_tpu_f901_takes_the_qdwh_path():
     text = _tpu_text(8, 901, debug_info=True)
     assert scopes.EIGH_SPLIT in text and scopes.EIGH_LEAF in text
     assert "dfrc.eigh/" in text and "_eigh_work" not in text
+    assert scopes.EIGH_REFINE not in text
     # one matrix at a time: the polar iteration's QR is of [2F, F]
     assert "tensor<1802x901xf32>" in _qr_operands(text)
     # XLA's own path: JAX's agenda
@@ -176,11 +178,11 @@ def test_lowering_tpu_f901_takes_the_qdwh_path():
 
 
 def test_lowering_small_f_and_cpu_unchanged():
-    # F <= 256: the native batched Jacobi, exactly as a plain vmapped eigh
-    text = _tpu_text(16, 31)
-    assert "@Eigh" in text and scopes.EIGH_SPLIT not in _tpu_text(16, 31, True)
-    with mock.patch.object(ridge, "_eigh", lambda g: tuple(jnp.linalg.eigh(g))):
-        assert _tpu_text(16, 31) == text
+    # F <= 256: the native batched Jacobi once, then the refinement's Jacobi
+    # sweep on the Gram in its basis (rotations, no second Eigh)
+    debug = _tpu_text(16, 31, True)
+    assert debug.count("@Eigh") == 1 and scopes.EIGH_SPLIT not in debug
+    assert scopes.EIGH_REFINE in debug and "dfrc.eigh/" in debug
     # the CPU keeps LAPACK, bit for bit, and lowers no QDWH path
     g = jnp.asarray(_batch(3, 300)[1])
     ours = jax.jit(jax.vmap(ridge._eigh))(g)
@@ -189,3 +191,32 @@ def test_lowering_small_f_and_cpu_unchanged():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(p))
     cpu = jax.jit(jax.vmap(ridge._eigh)).lower(g).as_text(debug_info=True)
     assert scopes.EIGH_SPLIT not in cpu and "syevd" in cpu
+
+
+@pytest.mark.parametrize("kind", ["narma_gram", "rank_deficient", "clustered"])
+def test_rayleigh_ritz_step_matches_float64(kind):
+    """The refinement of the F <= 256 eigh on the TPU (``_jacobi_refined``),
+    here on the CPU's eigh: from a basis rotated ~3e-6 off, as the TPU's
+    Jacobi leaves it (some 40-60 eps of residual), one Rayleigh-Ritz step
+    gives eigenvalues, orthogonality and residuals at LAPACK's own levels
+    (up to 10 eps of the largest eigenvalue on these spectra)."""
+    import scipy.linalg
+
+    f = 31
+    rng = np.random.default_rng([f, KINDS.index(kind)])
+    h = _matrix(kind, f, rng)
+    h64 = h.astype(np.float64)
+    ref, q64 = np.linalg.eigh(h64)
+    scale = np.max(np.abs(ref))
+    a = 2e-6 * rng.standard_normal((f, f))
+    q_off = (q64 @ scipy.linalg.expm(a - a.T)).astype(np.float32)
+    off = h64 @ q_off - q_off * (np.diag(q_off.T @ h64 @ q_off))
+    assert np.max(np.abs(off)) > 30 * EPS * scale
+    vals, q = (np.asarray(x, np.float64)
+               for x in jax.jit(ridge._rayleigh_ritz)(jnp.asarray(h), jnp.asarray(q_off)))
+    np.testing.assert_allclose(vals, ref, rtol=0, atol=16 * EPS * scale)
+    np.testing.assert_allclose(q.T @ q, np.eye(f), rtol=0, atol=16 * EPS)
+    assert np.max(np.linalg.norm(h64 @ q - q * vals, axis=0)) <= 16 * EPS * scale
+    # the whole refined eigh, as on the TPU, agrees with LAPACK's own
+    vals_r, _ = jax.jit(ridge._jacobi_refined)(jnp.asarray(h))
+    np.testing.assert_allclose(np.asarray(vals_r), ref, rtol=0, atol=16 * EPS * scale)

@@ -6,8 +6,10 @@ VMEM than the kernel may use, compiles there and is refused on the chip.
 These tests compile each kernel ahead of time for a v5e topology that is
 described, not attached, at the widths the chip runs (NARMA10's N = 900,
 the batches ``auto_block_s`` tiles, a Gram at F = 901 padded to 1024), and
-assert that the kernel reached Mosaic as a ``tpu_custom_call``.  The GCV
-solve's QDWH ``eigh`` is compiled under a 4-device mesh the same way.
+assert that the kernel reached Mosaic as a ``tpu_custom_call``.  The CMT
+cavity's sweep kernel, with per-lane parameter tiles, is compiled at the
+benchmark's 4096 lanes.  The GCV solve's QDWH ``eigh`` is compiled under a
+4-device mesh the same way.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and a file that loaded it
@@ -87,6 +89,27 @@ def test_dfr_scan_compiles_at_small_widths(one_chip, n_nodes):
     assert kernels["dfr_scan"] == 1, kernels
 
 
+def test_dfr_scan_with_parameter_tiles_compiles_at_sweep_width(one_chip):
+    """The CMT cavity's scan with per-lane (detune, loss, power) tiles at
+    the sweep cell's G = 4096 lanes and N = 30."""
+    from repro.devices import CMTSweepParams, MRCavityCMT
+
+    batch, n_nodes = 4096, 30
+    block_s = auto_block_s(batch)
+    s_total = padded_lanes(batch, block_s) // LANES
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    tile = sds((s_total, LANES))
+    kernels = _compile(
+        lambda j, m, s0, p: dfr_scan_tiled(MRCavityCMT(), j, m, s0,
+                                           block_s=block_s, params=p),
+        sds((K_PERIODS, s_total, LANES)), sds((n_nodes, 1)),
+        sds((n_nodes, s_total, LANES)), CMTSweepParams(tile, tile, tile))
+    assert kernels["dfr_scan"] == 1, kernels
+
+
 @pytest.mark.parametrize("x_dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 def test_gram_fold_compiles_at_narma_width(one_chip, x_dtype):
@@ -161,3 +184,4 @@ def test_gcv_solve_sharded_over_four_chips(topo):
     assert scopes.EIGH_SPLIT in text
     for collective in ("all-gather", "all-to-all", "collective-permute", "all-reduce"):
         assert collective not in text, collective
+
